@@ -76,8 +76,7 @@ def fig4_lowering_blocksize():
     kh, kw, _, cout = w.shape
     ho, wo = (h - kh) + 1, (wd - kw) + 1           # stride 1, VALID
     for bp in (1, 2, 4, 8, 16):
-        us = _timeit(lambda: lc.lowering_conv(x, w, stride=1, bp=bp, rb=7,
-                                              interpret=True),
+        us = _timeit(lambda: lc.lowering_conv(x, w, stride=1, bp=bp, rb=7),
                      warmup=1, iters=3).median_s * 1e6
         bp_c, rb_c = choose_tiles(b, ho, bp, 7)    # tiles the kernel ran
         vm = vmem_bytes(bp=bp_c, rb=rb_c, h=h, w=wd, cin=cin, kh=kh, kw=kw,

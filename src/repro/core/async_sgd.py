@@ -171,7 +171,6 @@ def apply_grouped_update(params, grads, mom_buf, *, strategy: str, lr: float,
                          head_mask=None,
                          group_weights: Optional[Sequence[float]] = None,
                          update_impl: str = "xla",
-                         interpret: Optional[bool] = None,
                          coeffs=None, hcoeffs=None):
     """Apply one round of grouped updates (``grads`` leading axis = g) via
     either strategy — the single update-application entry point shared by
@@ -200,7 +199,7 @@ def apply_grouped_update(params, grads, mom_buf, *, strategy: str, lr: float,
                               group_weights=group_weights)
     return fused_group_update(params, grads, mom_buf, coeffs=coeffs,
                               head_coeffs=hcoeffs, head_mask=head_mask,
-                              impl=update_impl, interpret=interpret)
+                              impl=update_impl)
 
 
 def head_mask_tree(params, head_filter: Optional[Callable]):
@@ -217,7 +216,6 @@ def make_grouped_train_step(loss_fn: Callable, *, num_groups: int, lr: float,
                             head_filter: Optional[Callable] = None,
                             grad_accum: int = 1, strategy: str = "fused",
                             update_impl: str = "xla",
-                            interpret: Optional[bool] = None,
                             group_weights: Optional[Sequence[float]] = None):
     """Build ``step(params, mom_buf, batches) -> (params, mom_buf, loss)``.
 
@@ -233,8 +231,7 @@ def make_grouped_train_step(loss_fn: Callable, *, num_groups: int, lr: float,
     ``strategy``: "fused" (default) applies the closed form of the g
     sub-steps in one fused pass; "scan" is the literal sequential
     reference. ``update_impl``: "xla" or "pallas" leaf kernel for the
-    fused path; ``interpret`` forces the Pallas interpreter (default:
-    compile natively on TPU, interpret elsewhere).
+    fused path (compiled on TPU, interpreted elsewhere).
 
     ``group_weights``: per-group batch shares from a heterogeneous
     allocation (``cluster.allocator.Allocation.weights``). Gradients are
@@ -246,8 +243,6 @@ def make_grouped_train_step(loss_fn: Callable, *, num_groups: int, lr: float,
         raise ValueError(f"unknown strategy {strategy!r}")
     if group_weights is not None:
         group_weights = tuple(float(w) for w in group_weights)
-    # interpret=None flows through to the leaf dispatch, which resolves it
-    # (compile natively on TPU, interpret elsewhere) in one place
     g = num_groups
     coeffs = grouped_coeffs(g, lr=lr, momentum=momentum,
                             weight_decay=weight_decay,
@@ -275,7 +270,7 @@ def make_grouped_train_step(loss_fn: Callable, *, num_groups: int, lr: float,
             momentum=momentum, weight_decay=weight_decay,
             head_mask=head_mask_tree(params, head_filter),
             group_weights=group_weights, update_impl=update_impl,
-            interpret=interpret, coeffs=coeffs, hcoeffs=hcoeffs)
+            coeffs=coeffs, hcoeffs=hcoeffs)
         return params, mom_buf, losses.mean()
 
     return step

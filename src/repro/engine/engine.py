@@ -90,7 +90,7 @@ class Engine:
                  group_weights: Optional[Sequence[float]] = None,
                  micro_sizes: Optional[Sequence[int]] = None,
                  head_filter: Optional[Callable] = None,
-                 update_impl: str = "xla", interpret: Optional[bool] = None,
+                 update_impl: str = "xla",
                  exec_mode: str = "auto", num_devices: Optional[int] = None,
                  mp: int = 1, sharding_rules=None,
                  donate: bool = True,
@@ -117,7 +117,7 @@ class Engine:
         self.micro_sizes = (tuple(int(s) for s in micro_sizes)
                             if micro_sizes is not None else None)
         self.head_filter = head_filter
-        self.update_impl, self.interpret = update_impl, interpret
+        self.update_impl = update_impl
         self.exec_mode, self.num_devices = exec_mode, num_devices
         self.mp = int(mp)
         if self.mp < 1:

@@ -81,8 +81,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.async_sgd import apply_grouped_update, head_mask_tree
 from repro.engine.buckets import assign_buckets, pack_bucket, unpack_bucket
@@ -149,7 +148,6 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
                            head_filter: Optional[Callable] = None,
                            group_weights: Optional[Sequence[float]] = None,
                            update_impl: str = "xla",
-                           interpret: Optional[bool] = None,
                            bucket_bytes: int = DEFAULT_BUCKET_BYTES,
                            sharding_rules=None):
     """Build the mesh-sharded ``step(params, mom, device_batch)``.
@@ -175,6 +173,13 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
     ``sharding_rules``: optional explicit ``(regex-path-window, spec)``
     rules forwarded to ``engine_param_specs`` (first match wins; the
     TENSOR_PREF table and auto-derivation cover unmatched leaves).
+
+    ``step.place(params, mom)`` puts both trees where the step keeps
+    them (committed ``NamedSharding``s on ``mesh``). Calling the step
+    on placed trees from the first round on makes every round's inputs
+    look alike to ``jit``: unplaced first-round inputs would compile the
+    step a second time on round two, when the outputs of round one come
+    back placed.
     """
     g, k = mesh.shape["group"], mesh.shape["data"]
     mp = int(mesh.shape.get("mp", 1))
@@ -189,19 +194,30 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
     else:
         coeffs = hcoeffs = None
 
+    def specs_of(params):
+        if mp == 1:
+            return P()
+        from repro.sharding.rules import engine_param_specs
+        return engine_param_specs(params, mesh, rules=sharding_rules)
+
+    def place(params, mom_buf):
+        specs = specs_of(params)
+        sh = (NamedSharding(mesh, specs) if isinstance(specs, P) else
+              jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                           is_leaf=lambda x: isinstance(x, P)))
+        return jax.device_put(params, sh), jax.device_put(mom_buf, sh)
+
     def step(params, mom_buf, dbatch):
         head_mask = head_mask_tree(params, head_filter)
         tdef = jax.tree.structure(params)
+        param_specs = specs_of(params)
         if mp > 1:
-            from repro.sharding.rules import engine_param_specs, spec_mp_dim
-            pspecs = engine_param_specs(params, mesh, rules=sharding_rules)
+            from repro.sharding.rules import spec_mp_dim
             mp_dims = [spec_mp_dim(s, "mp") for s in
-                       jax.tree.leaves(pspecs,
+                       jax.tree.leaves(param_specs,
                                        is_leaf=lambda x: isinstance(x, P))]
-            param_specs = pspecs
         else:
             mp_dims = None
-            param_specs = P()
 
         def shard_fn(p, v, bt):
             local = jax.tree.map(lambda t: t[0, 0], bt)   # this device's shard
@@ -253,7 +269,7 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
                     p, grad, v, strategy=strategy, lr=lr, momentum=momentum,
                     weight_decay=weight_decay, head_mask=head_mask,
                     group_weights=group_weights, update_impl=update_impl,
-                    interpret=interpret, coeffs=coeffs, hcoeffs=hcoeffs)
+                    coeffs=coeffs, hcoeffs=hcoeffs)
                 return p, v, losses
 
             # ---- overlapped bucketed exchange ----
@@ -288,7 +304,7 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
                     wn, vn = fused_bucket_update(
                         pack_bucket(b, flat_p), pack_bucket(b, flat_v), gs,
                         coeffs=hcoeffs if b.is_head else coeffs,
-                        impl=update_impl, interpret=interpret)
+                        impl=update_impl)
                     for i, w_leaf, v_leaf in zip(b.indices,
                                                  unpack_bucket(b, wn),
                                                  unpack_bucket(b, vn)):
@@ -307,19 +323,20 @@ def make_spmd_grouped_step(loss_fn: Callable, mesh: Mesh, *, lr: float,
                     tree.unflatten(flat_v), strategy=strategy,
                     lr=lr, momentum=momentum, weight_decay=weight_decay,
                     head_mask=head_mask, group_weights=group_weights,
-                    update_impl=update_impl, interpret=interpret)
+                    update_impl=update_impl)
                 new_p = tree.flatten_up_to(p2)
                 new_v = tree.flatten_up_to(v2)
             return tree.unflatten(new_p), tree.unflatten(new_v), losses
 
-        return shard_map(
-            shard_fn, mesh=mesh, check_rep=False,
+        return jax.shard_map(
+            shard_fn, mesh=mesh, check_vma=False,
             in_specs=(param_specs, param_specs, P("group", "data")),
             out_specs=(param_specs, param_specs, P()))(params, mom_buf,
                                                        dbatch)
 
     step.mesh_shape = (g, k, mp)
     step.bucket_bytes = bucket_bytes
+    step.place = place
     return step
 
 
@@ -330,7 +347,6 @@ def make_reference_grouped_step(loss_fn: Callable, g: int, k: int, *,
                                 head_filter: Optional[Callable] = None,
                                 group_weights: Optional[Sequence[float]] = None,
                                 update_impl: str = "xla",
-                                interpret: Optional[bool] = None,
                                 bucket_bytes: int = DEFAULT_BUCKET_BYTES):
     """Single-device reference of the SPMD step: the same (g, k) shard
     structure executed sequentially (``lax.map`` over shards), the same
@@ -351,8 +367,7 @@ def make_reference_grouped_step(loss_fn: Callable, g: int, k: int, *,
             params, grads, mom_buf, strategy=strategy, lr=lr,
             momentum=momentum, weight_decay=weight_decay,
             head_mask=head_mask_tree(params, head_filter),
-            group_weights=group_weights, update_impl=update_impl,
-            interpret=interpret)
+            group_weights=group_weights, update_impl=update_impl)
         return params_n, mom_n, losses.reshape(g, k)
 
     step.mesh_shape = (g, k)

@@ -95,6 +95,9 @@ class _BuiltStep:
         return np.asarray(loss, np.float64).mean()
 
     def __call__(self, params, mom, batch):
+        place = getattr(self.raw, "place", None)     # spmd: onto the mesh
+        if place is not None:
+            params, mom = place(params, mom)
         params, mom, loss = self.fn(params, mom, self.prepare(batch))
         return params, mom, self.scalar_loss(loss)
 
@@ -124,8 +127,7 @@ class GroupedStrategy(Strategy):
                           strategy=self.update,
                           head_filter=engine.head_filter,
                           group_weights=weights,
-                          update_impl=engine.update_impl,
-                          interpret=engine.interpret)
+                          update_impl=engine.update_impl)
             if mode == "spmd":
                 raw = make_spmd_grouped_step(engine.loss_fn, mesh,
                                              bucket_bytes=engine.bucket_bytes,

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the hot spots the paper optimizes (lowering conv,
+fused grouped update) and for the serving decode path (paged and flash
+attention), plus the one rule that decides how every kernel runs."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """True exactly when the default backend is not a TPU: Pallas kernels
+    compile natively on a TPU and run in the Pallas interpreter everywhere
+    else. Every kernel wrapper asks this at trace time; none takes an
+    ``interpret`` argument, so no path can run a kernel interpreted on
+    the chip."""
+    return jax.default_backend() != "tpu"

@@ -33,11 +33,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
 def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             bq, bk, causal, window, scale, n_kv):
+    b = pl.program_id(0)
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -52,7 +55,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     v = v_ref[0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
-    off = off_ref[0, 0]                             # absolute pos of q row 0
+    off = off_ref[b]                                # absolute pos of q row 0
     qpos = off + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     ok = jnp.ones((bq, bk), dtype=bool)
@@ -81,13 +84,13 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
-                           bq: int = 128, bk: int = 128,
-                           interpret: bool = False, kv_group: int = 1,
+                           bq: int = 128, bk: int = 128, kv_group: int = 1,
                            q_offsets=None):
     """q: (BH, Sq, hd); k, v: (BH // kv_group, Sk, hd) — batch and heads
     pre-folded, q head-major so kv row = (bh // (K*G))*K + (bh % (K*G))//G.
     ``q_offsets``: optional (BH,) int32 absolute position of each row's
-    first query (decode: the row's token position; default 0)."""
+    first query (decode: the row's token position; default 0), read by
+    scalar prefetch into SMEM before the grid starts."""
     bh, sq, hd = q.shape
     if kv_group < 1 or bh % kv_group:
         raise ValueError(f"kv_group={kv_group} must divide BH={bh}")
@@ -106,7 +109,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
     scale = 1.0 / math.sqrt(hd)
     if q_offsets is None:
         q_offsets = jnp.zeros((bh,), jnp.int32)
-    offs = q_offsets.astype(jnp.int32).reshape(bh, 1)
+    offs = q_offsets.astype(jnp.int32).reshape(bh)
 
     # head-major q rows: bh = batch*H + head with H = K*G, so the kv row
     # batch*K + head//G equals bh // G exactly (head < K*G) — the whole
@@ -115,23 +118,26 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
     def kv_row(b, _g=kv_group):
         return b // _g
 
-    return pl.pallas_call(
-        functools.partial(_kernel, bq=bq, bk=bk, causal=causal, window=window,
-                          scale=scale, n_kv=n_kv),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-            pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, hd), lambda b, i, j: (kv_row(b), j, 0)),
-            pl.BlockSpec((1, bk, hd), lambda b, i, j: (kv_row(b), j, 0)),
+            pl.BlockSpec((1, bq, hd), lambda b, i, j, off: (b, i, 0)),
+            pl.BlockSpec((1, bk, hd), lambda b, i, j, off: (kv_row(b), j, 0)),
+            pl.BlockSpec((1, bk, hd), lambda b, i, j, off: (kv_row(b), j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j, off: (b, i, 0)),
         scratch_shapes=[
             # running max / denom / accumulator, fp32 in VMEM
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, bq=bq, bk=bk, causal=causal, window=window,
+                          scale=scale, n_kv=n_kv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
+        interpret=kernels.interpret_mode(),
     )(offs, q, k, v)

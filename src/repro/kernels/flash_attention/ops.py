@@ -24,17 +24,17 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("causal", "window", "bq", "bk", "interpret"))
+                   static_argnames=("causal", "window", "bq", "bk"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True,
-                    q_offsets=None):
+                    bq: int = 128, bk: int = 128, q_offsets=None):
     """q: (B, Sq, H, hd); k,v: (B, Sk, K, hd) with H = K*G (GQA: the
     query-head -> kv-head group map runs inside the kernel's flattened
     batch dimension; K/V are never repeated). ``q_offsets``: optional
     (B,) int32 absolute position of each batch row's first query (decode
     rows at per-request positions). Returns (B, Sq, H, hd).
 
-    interpret=True on CPU (this container); False on real TPU.
+    Compiled on a TPU, interpreted elsewhere
+    (``repro.kernels.interpret_mode``).
     """
     b, sq, h, hd = q.shape
     kh = k.shape[2]
@@ -48,6 +48,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         offs = jnp.broadcast_to(
             q_offsets.astype(jnp.int32)[:, None], (b, h)).reshape(b * h)
     of = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
-                                bq=bq, bk=bk, interpret=interpret,
-                                kv_group=h // kh, q_offsets=offs)
+                                bq=bq, bk=bk, kv_group=h // kh,
+                                q_offsets=offs)
     return of.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)

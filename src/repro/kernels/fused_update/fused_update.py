@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.optim.closed_form import GroupedCoeffs
 
 LANE = 128       # TPU lane width (last dim of every tile)
@@ -51,14 +52,14 @@ def _kernel(w_ref, v_ref, g_ref, wo_ref, vo_ref, *, coeffs: GroupedCoeffs):
 
 
 def fused_update_pallas(w: jax.Array, v: jax.Array, gstack: jax.Array,
-                        coeffs: GroupedCoeffs, *, block_rows: int = 256,
-                        interpret: bool = False):
+                        coeffs: GroupedCoeffs, *, block_rows: int = 256):
     """One leaf or one bucket slab: w/v any shape (a flat (n,) packing of
     several leaves works — everything is flattened to lane tiles anyway),
     gstack (g, *w.shape). Returns (w_new, v_new).
 
-    On CPU (this container) run with interpret=True; the XLA reference in
-    ref.py is the production non-TPU path.
+    Compiled on a TPU, interpreted elsewhere
+    (``repro.kernels.interpret_mode``); the XLA reference in ref.py is the
+    production non-TPU path.
     """
     g = gstack.shape[0]
     if g != coeffs.num_groups:
@@ -87,7 +88,7 @@ def fused_update_pallas(w: jax.Array, v: jax.Array, gstack: jax.Array,
                    pl.BlockSpec((br, LANE), lambda r: (r, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows_p, LANE), w.dtype),
                    jax.ShapeDtypeStruct((rows_p, LANE), v.dtype)],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(w2, v2, g2)
     return (wn.reshape(-1)[:n].reshape(w.shape),
             vn.reshape(-1)[:n].reshape(v.shape))

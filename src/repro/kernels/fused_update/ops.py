@@ -13,32 +13,28 @@ from repro.optim.closed_form import GroupedCoeffs
 
 
 def _leaf_update(w, v, gstack, coeffs: GroupedCoeffs, *, impl: str,
-                 block_rows: int, interpret):
-    if interpret is None:    # compile natively on TPU, interpret elsewhere
-        interpret = jax.default_backend() != "tpu"
+                 block_rows: int):
     if impl == "pallas":
         return fused_update_pallas(w, v, gstack, coeffs,
-                                   block_rows=block_rows, interpret=interpret)
+                                   block_rows=block_rows)
     if impl != "xla":
         raise ValueError(f"unknown impl {impl!r}")
     return fused_update_ref(w, v, gstack, coeffs)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("coeffs", "impl", "block_rows",
-                                    "interpret"))
+                   static_argnames=("coeffs", "impl", "block_rows"))
 def fused_update(w, v, gstack, *, coeffs: GroupedCoeffs, impl: str = "xla",
-                 block_rows: int = 256, interpret=None):
+                 block_rows: int = 256):
     """One leaf. impl='pallas' runs the TPU kernel (compiled on TPU,
-    interpret mode elsewhere when interpret is None); impl='xla' the
-    reference combination (production path off-TPU)."""
+    interpret mode elsewhere); impl='xla' the reference combination
+    (production path off-TPU)."""
     return _leaf_update(w, v, gstack, coeffs, impl=impl,
-                        block_rows=block_rows, interpret=interpret)
+                        block_rows=block_rows)
 
 
 def fused_bucket_update(w_slab, v_slab, gstack, *, coeffs: GroupedCoeffs,
-                        impl: str = "xla", block_rows: int = 256,
-                        interpret=None):
+                        impl: str = "xla", block_rows: int = 256):
     """Per-bucket slab update for the overlapped SPMD exchange: ``w_slab``
     / ``v_slab`` are (n,) flat packings of a bucket's leaves
     (``engine.buckets.pack_bucket``), ``gstack`` the gathered (g, n)
@@ -47,13 +43,12 @@ def fused_bucket_update(w_slab, v_slab, gstack, *, coeffs: GroupedCoeffs,
     bit-identical to the per-leaf updates it replaces. Not jitted: the
     caller traces inside ``shard_map``."""
     return _leaf_update(w_slab, v_slab, gstack, coeffs, impl=impl,
-                        block_rows=block_rows, interpret=interpret)
+                        block_rows=block_rows)
 
 
 def fused_group_update(params, grads, mom_buf, *, coeffs: GroupedCoeffs,
                        head_coeffs: GroupedCoeffs = None, head_mask=None,
-                       impl: str = "xla", block_rows: int = 256,
-                       interpret=None):
+                       impl: str = "xla", block_rows: int = 256):
     """Whole-tree fused update in ONE traversal.
 
     grads: same tree as params with a leading (g, ...) group axis per leaf.
@@ -75,8 +70,7 @@ def fused_group_update(params, grads, mom_buf, *, coeffs: GroupedCoeffs,
             raise ValueError("head_mask marks head leaves but head_coeffs "
                              "was not provided")
         c = head_coeffs if is_head else coeffs
-        wn, vn = _leaf_update(w, v, g, c, impl=impl, block_rows=block_rows,
-                              interpret=interpret)
+        wn, vn = _leaf_update(w, v, g, c, impl=impl, block_rows=block_rows)
         new_w.append(wn)
         new_v.append(vn)
     return tree.unflatten(new_w), tree.unflatten(new_v)

@@ -4,7 +4,7 @@ sweep, automated).
 ``autotune_tiles`` probes the ``choose_tiles``-resolved (b_p, r_b)
 candidates that fit under the ``vmem_bytes`` budget by timing the actual
 op (forward + backward through the custom VJP) with ``engine.timing``,
-and caches the winner per (input shape, kernel shape, stride, interpret).
+and caches the winner per (input shape, kernel shape, stride).
 Model code (``models.cnn._conv``) looks the cached choice up at trace
 time via ``cached_tiles`` and falls back to the defaults when the layer
 was never probed.
@@ -28,24 +28,23 @@ DEFAULT_BUDGET_BYTES = 4 << 20
 _TILE_CACHE: Dict[tuple, Tuple[Tuple[int, int], int]] = {}
 
 
-def _cache_key(x_shape, w_shape, stride: int, interpret: bool) -> tuple:
+def _cache_key(x_shape, w_shape, stride: int) -> tuple:
     """Keyed on the layer geometry WITHOUT the batch dimension: the engine
     traces the same conv at batch/g (group vmap) or batch/(g*k) (per-device
     shard), and a (b_p, r_b) probed at the global batch stays valid at any
     of them — ``choose_tiles`` re-clamps b_p to a divisor of whatever batch
     the kernel actually sees."""
-    return (tuple(x_shape)[1:], tuple(w_shape), int(stride), bool(interpret))
+    return (tuple(x_shape)[1:], tuple(w_shape), int(stride))
 
 
 def clear_tile_cache() -> None:
     _TILE_CACHE.clear()
 
 
-def cached_tiles(x_shape, w_shape, stride: int,
-                 interpret: bool = True) -> Tuple[int, int]:
+def cached_tiles(x_shape, w_shape, stride: int) -> Tuple[int, int]:
     """The autotuned (b_p, r_b) for this layer geometry (batch-agnostic —
     see ``_cache_key``), or the defaults if it was never probed."""
-    hit = _TILE_CACHE.get(_cache_key(x_shape, w_shape, stride, interpret))
+    hit = _TILE_CACHE.get(_cache_key(x_shape, w_shape, stride))
     return hit[0] if hit is not None else DEFAULT_TILES
 
 
@@ -89,7 +88,7 @@ def tile_candidates(x_shape, w_shape, stride: int, *,
 
 def autotune_tiles(x_shape, w_shape, stride: int = 1, *,
                    budget_bytes: int = DEFAULT_BUDGET_BYTES,
-                   interpret: bool = True, warmup: int = 1, iters: int = 3,
+                   warmup: int = 1, iters: int = 3,
                    key: Optional[jax.Array] = None) -> Tuple[int, int]:
     """Probe every in-budget tile candidate on the real op (forward +
     backward, jit-compiled) and cache the fastest. Idempotent per layer:
@@ -97,7 +96,7 @@ def autotune_tiles(x_shape, w_shape, stride: int = 1, *,
     cached choice no longer fits a (smaller) ``budget_bytes``, which
     forces a re-probe under the new budget. (A larger budget keeps the
     cached choice: still valid, possibly conservative.)"""
-    ck = _cache_key(x_shape, w_shape, stride, interpret)
+    ck = _cache_key(x_shape, w_shape, stride)
     hit = _TILE_CACHE.get(ck)
     if hit is not None:
         tiles, probed_budget = hit
@@ -116,7 +115,7 @@ def autotune_tiles(x_shape, w_shape, stride: int = 1, *,
         def fwd_bwd(x, w):
             y, vjp = jax.vjp(
                 lambda x, w: ops.lowering_conv(x, w, stride=stride, bp=bp,
-                                               rb=rb, interpret=interpret),
+                                               rb=rb),
                 x, w)
             return jax.tree.map(jnp.sum, vjp(jnp.ones_like(y)))
         return jax.jit(fwd_bwd)

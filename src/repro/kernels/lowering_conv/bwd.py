@@ -12,9 +12,10 @@ already built:
 The wgrad consumes the forward's lowered residual instead of re-lowering —
 the paper's trade-memory-for-GEMM move applied to the backward pass. Two
 implementations of each: an XLA reference (``*_xla``, the CPU training
-path) and a Pallas kernel (``*_pallas``, validated in interpret mode on
-CPU, tiled for VMEM on real TPU via the same ``choose_tiles`` /
-``vmem_bytes(pass_=...)`` model as the forward).
+path) and a Pallas kernel (``*_pallas``, interpreter only — on a TPU it
+raises like the forward, ``lowering_conv.require_interpreter`` — tiled by
+the same ``choose_tiles`` / ``vmem_bytes(pass_=...)`` model as the
+forward).
 """
 from __future__ import annotations
 
@@ -24,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lowering_conv.lowering_conv import choose_tiles
+from repro.kernels.lowering_conv.lowering_conv import (choose_tiles,
+                                                      require_interpreter)
 
 
 # ---------------------------------------------------------------------------
@@ -104,7 +106,7 @@ def _wgrad_kernel(low_ref, dy_ref, out_ref):
 
 
 def wgrad_pallas(lowered: jax.Array, dy: jax.Array, kshape, *, bp: int = 8,
-                 rb: int = 8, interpret: bool = False) -> jax.Array:
+                 rb: int = 8) -> jax.Array:
     """lowered: (B, Ho, Wo, kh*kw*Cin) forward residual (``return_lowered``
     layout); dy: (B, Ho, Wo, Cout). Returns dW (kh, kw, Cin, Cout)."""
     kh, kw, cin, cout = kshape
@@ -120,7 +122,7 @@ def wgrad_pallas(lowered: jax.Array, dy: jax.Array, kshape, *, bp: int = 8,
         ],
         out_specs=pl.BlockSpec((K, cout), lambda ib, ir: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((K, cout), lowered.dtype),
-        interpret=interpret,
+        interpret=require_interpreter(),
     )(lowered, dy)
     return dw_flat.reshape(kh, kw, cin, cout)
 
@@ -141,7 +143,7 @@ def _dgrad_kernel(dy_ref, kt_ref, dx_ref, *, kh, kw, stride, h, w):
 
 
 def dgrad_pallas(dy: jax.Array, w: jax.Array, x_shape, *, stride: int = 1,
-                 bp: int = 8, interpret: bool = False) -> jax.Array:
+                 bp: int = 8) -> jax.Array:
     """dy: (B, Ho, Wo, Cout); w: (kh, kw, Cin, Cout). Returns dX
     ``x_shape``. Grid over batch blocks only (see ``_dgrad_kernel``)."""
     b, h, wdim, cin = x_shape
@@ -159,5 +161,5 @@ def dgrad_pallas(dy: jax.Array, w: jax.Array, x_shape, *, stride: int = 1,
         ],
         out_specs=pl.BlockSpec((bp, h, wdim, cin), lambda ib: (ib, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x_shape, dy.dtype),
-        interpret=interpret,
+        interpret=require_interpreter(),
     )(dy, kt)

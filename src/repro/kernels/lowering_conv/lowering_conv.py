@@ -13,6 +13,15 @@ The paper's b_p knob (images lowered per GEMM) is the batch-block dimension
 of the BlockSpec; the rows-block rb tiles output rows so the GEMM M dim
 stays VMEM-resident. ``vmem_bytes`` exposes the footprint model
 (paper Fig. 4c: memory grows linearly in b_p).
+
+The kernels run only in the Pallas interpreter: on a TPU backend they
+raise (``require_interpreter``). Two things keep them from compiling
+there. ``lax.dynamic_slice`` inside the kernel (the output-row window of
+``_lower_block``) and the strided in-kernel slices have no Mosaic
+lowering, and each grid step holds whole ``(b_p, H, W, Cin)`` images in
+VMEM with ``Cin`` on the 128-wide lane axis (CaffeNet conv1: Cin = 3, so
+one 227x227 image pads to ~27 MB of VMEM). The training path on the chip
+is ``conv_impl="lowering"``, the same algorithm through XLA.
 """
 from __future__ import annotations
 
@@ -21,6 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import kernels
 
 
 def largest_divisor(n: int, cap: int) -> int:
@@ -37,6 +48,19 @@ def largest_divisor(n: int, cap: int) -> int:
                 best = max(best, n // d)
         d += 1
     return best
+
+
+def require_interpreter() -> bool:
+    """``interpret=True`` for the lowering-conv kernels, or a clear error
+    on a TPU backend, where they do not compile (module docstring)."""
+    if not kernels.interpret_mode():
+        raise NotImplementedError(
+            "lowering_conv Pallas kernels have no TPU lowering: in-kernel "
+            "lax.dynamic_slice/strided slices do not lower to Mosaic, and "
+            "whole (b_p, H, W, Cin) images with Cin on the lane axis "
+            "overflow VMEM; use conv_impl='lowering' (the XLA custom-VJP "
+            "path) on TPU")
+    return True
 
 
 def choose_tiles(b: int, ho: int, bp: int, rb: int) -> tuple:
@@ -89,7 +113,7 @@ def _kernel_with_lowered(d_ref, k_ref, r_ref, low_ref, *, kh, kw, stride, rb,
 
 
 def lowering_conv_pallas(x: jax.Array, w: jax.Array, *, stride: int = 1,
-                         bp: int = 8, rb: int = 8, interpret: bool = False,
+                         bp: int = 8, rb: int = 8,
                          return_lowered: bool = False):
     """x: (B,H,W,Cin); w: (kh,kw,Cin,Cout); VALID padding.
 
@@ -125,7 +149,7 @@ def lowering_conv_pallas(x: jax.Array, w: jax.Array, *, stride: int = 1,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=require_interpreter(),
     )(x, k_hat)
 
 

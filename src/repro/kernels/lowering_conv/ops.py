@@ -30,7 +30,7 @@ from repro.kernels.lowering_conv.ref import lower, lowered_conv_ref
 
 
 # ---------------------------------------------------------------------------
-# XLA path (the CPU training path)
+# XLA path (the training path, on the CPU and the TPU)
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -81,26 +81,22 @@ def lowering_conv_xla(x, w, *, stride: int = 1, needs_dgrad: bool = True):
 # Pallas path
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
-def _lc_pallas(x, w, stride, bp, rb, interpret, needs_dgrad, x_shape):
-    return lowering_conv_pallas(x, w, stride=stride, bp=bp, rb=rb,
-                                interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _lc_pallas(x, w, stride, bp, rb, needs_dgrad, x_shape):
+    return lowering_conv_pallas(x, w, stride=stride, bp=bp, rb=rb)
 
 
-def _lc_pallas_fwd(x, w, stride, bp, rb, interpret, needs_dgrad, x_shape):
+def _lc_pallas_fwd(x, w, stride, bp, rb, needs_dgrad, x_shape):
     r, lowered = lowering_conv_pallas(x, w, stride=stride, bp=bp, rb=rb,
-                                      interpret=interpret,
                                       return_lowered=True)
     return r, (lowered, w)
 
 
-def _lc_pallas_bwd(stride, bp, rb, interpret, needs_dgrad, x_shape, res, dy):
+def _lc_pallas_bwd(stride, bp, rb, needs_dgrad, x_shape, res, dy):
     lowered, w = res
-    dw = bwd.wgrad_pallas(lowered, dy, w.shape, bp=bp, rb=rb,
-                          interpret=interpret)
+    dw = bwd.wgrad_pallas(lowered, dy, w.shape, bp=bp, rb=rb)
     if needs_dgrad:
-        dx = bwd.dgrad_pallas(dy, w, x_shape, stride=stride, bp=bp,
-                              interpret=interpret)
+        dx = bwd.dgrad_pallas(dy, w, x_shape, stride=stride, bp=bp)
     else:
         dx = jnp.zeros(x_shape, dy.dtype)
     return dx, dw.astype(w.dtype)
@@ -110,23 +106,23 @@ _lc_pallas.defvjp(_lc_pallas_fwd, _lc_pallas_bwd)
 
 
 def lowering_conv_traced(x, w, *, stride: int = 1, bp: int = 8, rb: int = 8,
-                         interpret: bool = True, needs_dgrad: bool = True):
+                         needs_dgrad: bool = True):
     """Un-jitted Pallas form (see ``lowering_conv_xla_traced``)."""
-    return _lc_pallas(x, w, stride, bp, rb, interpret, needs_dgrad,
-                      tuple(x.shape))
+    return _lc_pallas(x, w, stride, bp, rb, needs_dgrad, tuple(x.shape))
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "bp", "rb",
-                                             "interpret", "needs_dgrad"))
+                                             "needs_dgrad"))
 def lowering_conv(x, w, *, stride: int = 1, bp: int = 8, rb: int = 8,
-                  interpret: bool = True, needs_dgrad: bool = True):
+                  needs_dgrad: bool = True):
     """Convolution via fused lowering+GEMM (Pallas), trainable through the
-    batched-GEMM backward kernels. On CPU (this container) the kernels run
-    in interpret mode; pass interpret=False on real TPU. Tile sizes come
-    from ``autotune.cached_tiles`` when the caller has probed them.
+    batched-GEMM backward kernels. The kernels run in the Pallas
+    interpreter and raise on a TPU backend (``lowering_conv`` module doc).
+    Tile sizes come from ``autotune.cached_tiles`` when the caller has
+    probed them.
     """
     return lowering_conv_traced(x, w, stride=stride, bp=bp, rb=rb,
-                                interpret=interpret, needs_dgrad=needs_dgrad)
+                                needs_dgrad=needs_dgrad)
 
 
 # ---------------------------------------------------------------------------

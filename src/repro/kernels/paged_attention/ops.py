@@ -7,7 +7,7 @@ layout ``serving.decode`` feeds the output projection. The GQA grouping
 (H = K * G, head index ``k * G + g``) matches ``layers._grouped_scores``
 so the paged kernel is a drop-in for the gathered dense path.
 
-``interpret=True`` on CPU (this container); False on real TPU.
+Compiled on a TPU, interpreted elsewhere (``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -18,9 +18,8 @@ import jax
 from repro.kernels.paged_attention.paged_attention import paged_attention_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def paged_attention(q, k_pages, v_pages, table, pos, *, window=None,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("window",))
+def paged_attention(q, k_pages, v_pages, table, pos, *, window=None):
     """q: (B, 1, H, hd); k_pages/v_pages: (P, page, K, hd) with H = K*G;
     table: (B, n_pages) int32 (page 0 = scratch); pos: (B,) int32 current
     absolute position per row (its K/V already written). ``window``
@@ -34,5 +33,5 @@ def paged_attention(q, k_pages, v_pages, table, pos, *, window=None,
         raise ValueError(f"H={h} must be a multiple of K={kh}")
     qg = q.reshape(b, kh, h // kh, hd)           # head h = k*G + g, grouped
     out = paged_attention_pallas(qg, k_pages, v_pages, table, pos,
-                                 window=window, interpret=interpret)
+                                 window=window)
     return out.reshape(b, 1, h, hd)

@@ -9,21 +9,22 @@ whether the request had 9 live tokens or 900. Here the K/V BlockSpec
 SMEM before the grid starts) to pick the physical page block for each
 grid step: pages are consumed in place, zero dense materialization.
 
-Grid: ``(B, K, n_pages)`` — batch rows, kv heads, then the slot's page
-list innermost and sequential (the flash running max / denominator /
-accumulator live in VMEM scratch across page steps). Three properties do
-the roofline work:
+Grid: ``(B, n_pages)`` — batch rows, then the slot's page list innermost
+and sequential (the flash running max / denominator / accumulator live in
+VMEM scratch across page steps). Three properties do the roofline work:
 
 - **Length-bounded walk.** Per-row ``pos`` (also scalar-prefetched)
   bounds the live page count ``jmax``; tail steps clamp their index map
   to the last live page — an unchanged block index means the pipeline
   skips the HBM fetch — and ``pl.when`` skips their compute entirely.
   Work scales with ``pos[b]``, not ``W = n_pages * page``.
-- **Repeat-free GQA.** The kv-head grid dimension feeds the K/V index
-  maps directly while the query block carries that head's ``G = H/K``
-  query rows, so K/V bytes stream once per kv head — the same
-  no-``jnp.repeat`` contract as ``flash_attention``'s ``kv_row`` trick,
-  expressed as a grid axis instead of a row divide.
+- **Repeat-free GQA.** Each grid step fetches one physical page for all
+  K kv heads — a ``(page, K, hd)`` block whose trailing ``(K, hd)`` dims
+  are the pool's own, which is the tiling the TPU compiler accepts — and
+  a static loop over the kv heads scores each head's ``G = H/K`` query
+  rows against its own K/V rows. K/V bytes stream once per page, never
+  repeated per query head (the same no-``jnp.repeat`` contract as
+  ``flash_attention``'s ``kv_row`` trick).
 - **Ring-aware masking.** With ``window`` set the cache is a ring:
   slot ``s`` holds absolute position ``base + s`` or ``base - W + s``
   depending on which side of the write head it sits (the reference
@@ -48,6 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -65,7 +68,7 @@ def _live_jmax(pos, *, page: int, n_pages: int, ring: bool):
 def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, page, n_pages, scale, window, W):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -79,10 +82,6 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j <= jmax)
     def _flash_step():
-        q = q_ref[0, 0]                              # (G, hd)
-        k = k_ref[0, :, 0]                           # (page, hd)
-        v = v_ref[0, :, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         slot = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         if window is None:
             ok = slot <= pos
@@ -91,26 +90,31 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             base = pos - pos % W
             absp = jnp.where(slot <= pos % W, base + slot, base - W + slot)
             ok = (absp <= pos) & (absp >= 0) & (absp > pos - window)
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = (acc_scr[...] * alpha
-                        + jnp.dot(p.astype(v.dtype), v,
-                                  preferred_element_type=jnp.float32))
-        m_scr[...] = m_new
+        for h in range(q_ref.shape[1]):              # static loop, kv heads
+            q = q_ref[0, h]                          # (G, hd)
+            k = k_ref[0, :, h, :]                    # (page, hd)
+            v = v_ref[0, :, h, :]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_scr[h]
+            l_prev = l_scr[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = (acc_scr[h] * alpha
+                          + jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32))
+            m_scr[h] = m_new
 
     @pl.when(j == n_pages - 1)
     def _out():
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, table, pos, *,
-                           window=None, interpret: bool = False):
+                           window=None):
     """q: (B, K, G, hd) one query token per row, grouped by kv head;
     k_pages/v_pages: (P, page, K, hd) physical pools; table: (B, n_pages)
     int32 page ids; pos: (B,) int32 absolute position of each row's
@@ -128,29 +132,29 @@ def paged_attention_pallas(q, k_pages, v_pages, table, pos, *,
     scale = 1.0 / math.sqrt(hd)
     ring = window is not None
 
-    def kv_map(b, h, j, tbl, pos_s):
+    def kv_map(b, j, tbl, pos_s):
         # THE table walk: clamp dead-tail steps to the last live page so
         # their block index repeats (no new fetch), then map the logical
         # page j to its physical page id.
         jj = jnp.minimum(j, _live_jmax(pos_s[b], page=page,
                                        n_pages=n_pages, ring=ring))
-        return (tbl[b, jj], 0, h, 0)
+        return (tbl[b, jj], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, n_pages),
+        grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, tbl, pos_s: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, K, G, hd), lambda b, j, tbl, pos_s: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, K, hd), kv_map),
+            pl.BlockSpec((1, page, K, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, tbl, pos_s: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, K, G, hd),
+                               lambda b, j, tbl, pos_s: (b, 0, 0, 0)),
         scratch_shapes=[
-            # running max / denom / accumulator, fp32 in VMEM
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            # per-kv-head running max / denom / accumulator, fp32 in VMEM
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -158,5 +162,5 @@ def paged_attention_pallas(q, k_pages, v_pages, table, pos, *,
                           window=window, W=W),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, k_pages, v_pages)

@@ -1,12 +1,15 @@
 import os
 import sys
-# Forced host device pool, set before jax initializes its backend: 8 for the
-# --host-smoke CI lane (matches the tier-1 test pool), 512 for production
-# dry-runs. An externally provided XLA_FLAGS (test harness subprocess) wins.
+# Forced host device pool on the CPU backend, set before jax initializes:
+# 8 for the --host-smoke CI lane (matches the tier-1 test pool), 512 for
+# production dry-runs. An externally provided XLA_FLAGS (test harness
+# subprocess) wins. The pool is host devices by design, so the backend is
+# pinned to the CPU even on a machine with an accelerator.
 os.environ.setdefault(
     "XLA_FLAGS",
     "--xla_force_host_platform_device_count="
     + ("8" if "--host-smoke" in sys.argv[1:] else "512"))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input-shape x mesh) combination with ShapeDtypeStruct

@@ -12,13 +12,32 @@ Axis naming is unified on the engine's ("group", "data", "mp") canon:
 keep their historical ("pod", "data", "model") naming as a compat shim for
 the recorded dry-run artifacts — and the engine's group mesh consume the
 same rule code.
+
+Every mesh is built by ``make_mesh``, with every axis ``Auto``: the
+steps here shard by ``with_sharding_constraint`` and ``shard_map``
+specs, which refer to Auto axes only (``jax.make_mesh`` alone builds
+Explicit axes).
 """
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 #: canonical engine mesh axes (model-parallel axis last)
 GROUP_MESH_AXES = ("group", "data", "mp")
+
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """The one mesh constructor: ``shape`` over ``axes``, all axes Auto.
+    ``devices`` (any array-like of ``prod(shape)`` devices) pins the
+    device order; otherwise ``jax.make_mesh`` picks it from the visible
+    pool."""
+    axis_types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
+    return Mesh(np.asarray(devices).reshape(shape), tuple(axes),
+                axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,12 +50,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU tests (requires >= data*model host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_host_smoke_mesh(data: int = 4, mp: int = 2, groups: int = 1):
@@ -45,7 +64,7 @@ def make_host_smoke_mesh(data: int = 4, mp: int = 2, groups: int = 1):
     ``data`` fsdp-style shards times ``mp`` model shards (requires
     >= groups*data*mp host devices). Param rules replicate over "group"
     (``sharding.rules.default_axes``), mirroring the engine."""
-    return jax.make_mesh((groups, data, mp), GROUP_MESH_AXES)
+    return make_mesh((groups, data, mp), GROUP_MESH_AXES)
 
 
 def make_group_mesh(groups: int, data: int = 1, mp: int = 1):
@@ -58,7 +77,6 @@ def make_group_mesh(groups: int, data: int = 1, mp: int = 1):
     first g*k*mp local devices, so it works on any prefix of the host/TPU
     device pool (CPU-testable via --xla_force_host_platform_device_count).
     """
-    from jax.sharding import Mesh
-
     from repro.engine.spmd import group_mesh_devices
-    return Mesh(group_mesh_devices(groups, data, mp), GROUP_MESH_AXES)
+    return make_mesh((groups, data, mp), GROUP_MESH_AXES,
+                     group_mesh_devices(groups, data, mp))

@@ -193,4 +193,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -106,7 +106,8 @@ def _plan(args, params, cfg):
     return plan
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The driver's command line, validated."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch",
                     choices=[*list_archs(), *sorted(C.CNN_CONFIGS)],
@@ -153,7 +154,8 @@ def main(argv=None):
                     help="CNN conv path (CNN archs only): lowering = "
                          "custom-VJP batched-GEMM train path (config "
                          "default), lowering_interpret = Pallas kernels "
-                         "with per-layer autotuned tiles, "
+                         "with per-layer autotuned tiles (Pallas "
+                         "interpreter only; raises on TPU), "
                          "lowering_autodiff = generic-autodiff baseline, "
                          "xla = native conv")
     ap.add_argument("--replay-trace", type=str, default="",
@@ -192,13 +194,18 @@ def main(argv=None):
         ap.error("--plan and --replay-trace are mutually exclusive "
                  "(a replay executes a recorded schedule; there is "
                  "nothing for the planner to allocate)")
+    return args
 
+
+def main(argv=None):
+    """Train per ``argv``; returns the per-round losses."""
+    args = parse_args(argv)
     # install a recording span tracer for the whole run (workload build,
     # autotune probes, engine loop) iff a trace export was requested —
     # otherwise every span below stays the shared no-op
     from repro.obs import spans
     with spans.maybe_traced(bool(args.trace_out)):
-        return _run(args)
+        return run(args)[1]
 
 
 def _export_obs(args, engine, groups: int, event_trace=None) -> None:
@@ -222,7 +229,11 @@ def _export_obs(args, engine, groups: int, event_trace=None) -> None:
               "https://ui.perfetto.dev)")
 
 
-def _run(args):
+def run(args, **engine_kw):
+    """Build the workload and the Engine from parsed ``args`` and train.
+    ``engine_kw`` reaches the grouped ``Engine`` as extra keyword
+    arguments (e.g. ``num_devices`` to pin the mesh width). Returns
+    ``(final_params, losses, engine)``."""
     name, params, loss_fn, data, head_filter, cfg = _build_workload(args)
     mom = init_momentum(params)
 
@@ -243,12 +254,12 @@ def _run(args):
               f"{float(t.staleness.mean()):.2f}, max {t.max_staleness}")
         # one microbatch per commit: the per-commit stream uses batch-size
         # microbatches, matching the per-group share of a grouped round
-        _, _, losses = engine.run(params, mom, data, steps=args.steps,
-                                  log_every=10)
+        params, _, losses = engine.run(params, mom, data, steps=args.steps,
+                                       log_every=10)
         print(f"final loss {np.mean(losses[-5:]):.4f} "
               f"(impl={args.replay_impl})")
         _export_obs(args, engine, trace.num_groups, event_trace=t)
-        return losses
+        return params, losses, engine
 
     groups, group_weights, micro_sizes, mp = args.groups, None, None, args.mp
     if args.plan:
@@ -276,7 +287,8 @@ def _run(args):
                     **({"bucket_bytes": args.bucket_bytes}
                        if args.bucket_bytes is not None else {}),
                     checkpoint_dir=args.ckpt,
-                    checkpoint_every=args.steps if args.ckpt else 0)
+                    checkpoint_every=args.steps if args.ckpt else 0,
+                    **engine_kw)
     print(f"arch={name} {engine.describe(groups, args.batch // groups)}"
           + (" (planned)" if args.plan else ""))
     params, mom, losses = engine.run(params, mom, data, steps=args.steps,
@@ -289,8 +301,10 @@ def _run(args):
     _export_obs(args, engine, groups)
     if args.ckpt:
         print("checkpointed to", args.ckpt)
-    return losses
+    return params, losses, engine
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

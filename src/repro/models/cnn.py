@@ -9,8 +9,9 @@ path, not a demo. ``conv_impl``:
 
   "lowering"            lowering + GEMM with the custom VJP (XLA form, the
                         CPU training path; docs/lowering_conv.md)
-  "lowering_interpret"  the Pallas kernels (interpret mode on CPU), tiles
-                        from the per-layer autotune cache
+  "lowering_interpret"  the Pallas kernels (Pallas interpreter only: on a
+                        TPU they raise NotImplementedError), tiles from
+                        the per-layer autotune cache
   "lowering_autodiff"   the same algorithm under generic XLA autodiff
                         (benchmark baseline)
   "xla"                 jax.lax.conv_general_dilated
@@ -113,11 +114,10 @@ def _conv(x, w, b, stride, impl, needs_dgrad=True):
         # _traced forms: the loss is always inside the engine's jit (and
         # possibly its group-vmap) — a nested jit there costs ~2x on CPU
         from repro.kernels.lowering_conv import autotune, ops as lc_ops
-        if impl.endswith("interpret"):    # Pallas kernels, interpret on CPU
+        if impl.endswith("interpret"):    # Pallas kernels (interpreter)
             bp, rb = autotune.cached_tiles(x.shape, w.shape, stride)
             y = lc_ops.lowering_conv_traced(x, w, stride=stride, bp=bp,
-                                            rb=rb, interpret=True,
-                                            needs_dgrad=needs_dgrad)
+                                            rb=rb, needs_dgrad=needs_dgrad)
         elif impl.endswith("autodiff"):   # generic-autodiff baseline
             from repro.kernels.lowering_conv.ref import lowered_conv_ref
             y = lowered_conv_ref(x, w, stride=stride)

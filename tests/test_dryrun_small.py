@@ -5,8 +5,11 @@ the test suite is untouched — the production 16x16 / 2x16x16 meshes run via
 import json
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
 
 SCRIPT = r"""
 import os
@@ -18,11 +21,12 @@ import jax.numpy as jnp
 from repro.configs import get_smoke_config
 from repro.configs.base import InputShape, TrainConfig
 from repro.launch import steps as ST
+from repro.launch.mesh import make_mesh
 from repro.sharding import rules as SH
 
 arch = sys.argv[1]
 cfg = get_smoke_config(arch)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 results = {}
 for shape in (InputShape("train", 32, 8, "train"),
               InputShape("prefill", 64, 8, "prefill"),
@@ -74,7 +78,7 @@ FAMILIES = ["qwen2-7b", "grok-1-314b", "mamba2-2.7b", "recurrentgemma-2b",
 def test_small_mesh_dryrun(arch):
     proc = subprocess.run([sys.executable, "-c", SCRIPT, arch],
                           capture_output=True, text=True, timeout=420,
-                          cwd="/root/repo")
+                          cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     assert line, proc.stdout
@@ -126,7 +130,7 @@ def test_algorithm1_plan_accepted_by_dryrun():
     planned ("group","data","mp") mesh."""
     proc = subprocess.run([sys.executable, "-c", PLAN_TO_DRYRUN_SCRIPT],
                           capture_output=True, text=True, timeout=420,
-                          cwd="/root/repo")
+                          cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     assert line, proc.stdout

@@ -33,6 +33,18 @@ def _tree_bits_equal(a, b):
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
 
+def _tree_close(a, b, rel=1e-6):
+    """Per leaf, max |a - b| <= rel * max |a|: the spmd == reference pin
+    where XLA fuses the same math differently in the two programs
+    (docs/engine.md, "Known caveats")."""
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        x, y = np.asarray(x), np.asarray(y)
+        scale = max(float(np.abs(x).max()), float(np.finfo(x.dtype).tiny))
+        if float(np.abs(x - y).max()) > rel * scale:
+            return False
+    return True
+
+
 def _run_pair(wl, *, strategy, g, weights=None, sizes=None, steps=3,
               lr=0.05, momentum=0.6, weight_decay=0.0, batch=32,
               **engine_kw):
@@ -89,12 +101,13 @@ def test_spmd_bitmatches_reference_weighted(strategy, g):
 def test_spmd_bitmatches_reference_cnn_head(strategy):
     """The paper's CNN workload with the merged-FC head filter: head
     params take the single averaged update, backbone the g stale updates —
-    identical on the mesh and the reference."""
+    the same on the mesh and the reference, to within the scan update's
+    FMA contraction (docs/engine.md, "Known caveats")."""
     wl = cnn_classify()
     (ps, ms, ls), (pr, mr, lr_) = _run_pair(wl, strategy=strategy, g=4,
                                             batch=16)
-    assert _tree_bits_equal(ps, pr), strategy
-    assert _tree_bits_equal(ms, mr), strategy
+    assert _tree_close(ps, pr), strategy
+    assert _tree_close(ms, mr), strategy
     assert float(ls) == float(lr_)
 
 
@@ -128,9 +141,10 @@ def test_spmd_matches_reference_weight_decay_one_ulp(strategy):
 @needs8
 def test_spmd_bitmatches_reference_transformer():
     """Model-agnosticism: the reduced token-LM through the same engine,
-    mesh vs reference, bitwise. (Transformer backward is exactly the case
-    where vmap-batched grads do NOT bit-match unbatched ones, which is
-    what the shard-structured reference exists for.)"""
+    mesh vs reference, to within the final-norm gradient's reduction
+    order (docs/engine.md, "Known caveats"). (Transformer backward is
+    exactly the case where vmap-batched grads do NOT bit-match unbatched
+    ones, which is what the shard-structured reference exists for.)"""
     from repro.configs import get_smoke_config
     from repro.models import transformer as T
 
@@ -153,8 +167,8 @@ def test_spmd_bitmatches_reference_transformer():
 
     (ps, ms, ls), (pr, mr, lr_) = _run_pair(WL, strategy="grouped-fused",
                                             g=2, steps=2, batch=8)
-    assert _tree_bits_equal(ps, pr)
-    assert _tree_bits_equal(ms, mr)
+    assert _tree_close(ps, pr)
+    assert _tree_close(ms, mr)
     assert float(ls) == float(lr_)
 
 
@@ -335,6 +349,27 @@ def test_run_then_step_reuses_compile():
     assert len(eng._steps) == 1      # still the single shared compile
     # the caller's buffers survived both protected entries
     assert np.isfinite(float(wl.loss_fn(params, b0)))
+
+
+@needs8
+@pytest.mark.parametrize("mp", [1, 2])
+def test_spmd_run_compiles_the_step_once(mp):
+    """Round two reuses round one's executable: the step's inputs are
+    placed on the mesh before the first round, so round one's placed
+    outputs look the same to jit (unplaced, a full-size step compiled
+    twice on a four-chip v5e)."""
+    wl = mlp_classify()
+    eng = Engine(wl.loss_fn, strategy="grouped-fused", num_groups=2,
+                 exec_mode="spmd", mp=mp, num_devices=4,
+                 head_filter=wl.head_filter)
+    params = wl.init(jax.random.PRNGKey(0))
+    mom = jax.tree.map(jnp.zeros_like, params)
+    batches = wl.sample_batches(jax.random.PRNGKey(1), 4, 32)
+    it = (jax.tree.map(lambda x: x[t], batches) for t in range(4))
+    eng.run(params, mom, it, steps=4)
+    (built,) = eng._steps.values()
+    assert built.mode == "spmd"
+    assert built.fn._cache_size() == 1
 
 
 def test_bucket_assignment_and_packing():

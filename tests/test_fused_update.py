@@ -58,8 +58,7 @@ def test_fused_matches_scan(g, mu, wd):
     hc = head_coeffs(g, lr=0.05, momentum=mu, weight_decay=wd)
     for impl in ("xla", "pallas"):
         p, v = fused_group_update(params, grads, mom, coeffs=c,
-                                  head_coeffs=hc, head_mask=mask, impl=impl,
-                                  interpret=True)
+                                  head_coeffs=hc, head_mask=mask, impl=impl)
         _assert_trees_close(ref_p, p)
         _assert_trees_close(ref_v, v)
 
@@ -129,7 +128,7 @@ def test_pallas_leaf_matches_ref(shape, dtype):
     gs = jax.random.normal(ks[2], (g,) + shape).astype(dtype)
     c = grouped_coeffs(g, lr=0.05, momentum=0.9, weight_decay=1e-4)
     rw, rv = fused_update_ref(w, v, gs, c)
-    pw, pv = fused_update_pallas(w, v, gs, c, interpret=True)
+    pw, pv = fused_update_pallas(w, v, gs, c)
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(pw, np.float32),
                                np.asarray(rw, np.float32), rtol=tol, atol=tol)
@@ -144,7 +143,7 @@ def test_public_leaf_entry_point():
     gs = jax.random.normal(ks[2], (4, 40, 9))
     c = grouped_coeffs(4, lr=0.05, momentum=0.9, weight_decay=1e-4)
     x = fused_update(w, v, gs, coeffs=c, impl="xla")
-    p = fused_update(w, v, gs, coeffs=c, impl="pallas", interpret=True)
+    p = fused_update(w, v, gs, coeffs=c, impl="pallas")
     _assert_trees_close(x, p, rtol=2e-6, atol=2e-6)
 
 
@@ -155,9 +154,9 @@ def test_pallas_block_sizes():
     w, v = jax.random.normal(ks[0], shape), jax.random.normal(ks[1], shape)
     gs = jax.random.normal(ks[2], (g,) + shape)
     c = grouped_coeffs(g, lr=0.1, momentum=0.5)
-    ref = fused_update_pallas(w, v, gs, c, block_rows=256, interpret=True)
+    ref = fused_update_pallas(w, v, gs, c, block_rows=256)
     for br in (8, 16, 64):
-        out = fused_update_pallas(w, v, gs, c, block_rows=br, interpret=True)
+        out = fused_update_pallas(w, v, gs, c, block_rows=br)
         _assert_trees_close(ref, out, rtol=1e-6, atol=1e-7)
 
 

@@ -1,4 +1,4 @@
-"""Pallas kernel validation (interpret=True on CPU): shape/dtype sweeps +
+"""Pallas kernel validation (Pallas interpreter on CPU): shape/dtype sweeps +
 property tests against the pure-jnp oracles in ref.py."""
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ def test_lowering_conv_sweep(shape, kern, stride, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), shape).astype(dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), kern).astype(dtype)
     ref = lc_ref.conv_ref(x.astype(jnp.float32), w.astype(jnp.float32), stride)
-    out = lc_ops.lowering_conv(x, w, stride=stride, bp=2, rb=4, interpret=True)
+    out = lc_ops.lowering_conv(x, w, stride=stride, bp=2, rb=4)
     tol = 2e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=tol, atol=tol)
@@ -46,7 +46,7 @@ def test_lowering_conv_block_sizes(bp, rb):
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 10, 10, 3))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 3, 8))
     ref = lc_ref.conv_ref(x, w, 1)
-    out = lc_ops.lowering_conv(x, w, stride=1, bp=bp, rb=rb, interpret=True)
+    out = lc_ops.lowering_conv(x, w, stride=1, bp=bp, rb=rb)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -56,7 +56,7 @@ def test_lowering_matches_three_phase_ref():
     x = jax.random.normal(jax.random.PRNGKey(0), (3, 9, 9, 2))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 2, 4))
     a = lc_ref.lowered_conv_ref(x, w, 1)
-    b = lc_ops.lowering_conv(x, w, stride=1, interpret=True)
+    b = lc_ops.lowering_conv(x, w, stride=1)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
 
 
@@ -83,7 +83,7 @@ else:
         x = jax.random.normal(ks[0], (b, hw, hw, cin))
         w = jax.random.normal(ks[1], (k, k, cin, cout))
         ref = lc_ref.conv_ref(x, w, 1)
-        out = lc_ops.lowering_conv(x, w, stride=1, bp=2, rb=3, interpret=True)
+        out = lc_ops.lowering_conv(x, w, stride=1, bp=2, rb=3)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=3e-5, atol=3e-5)
 
@@ -112,7 +112,7 @@ def test_flash_attention_sweep(s, h, kv, hd, window, dtype):
         jnp.repeat(v, rep, 2).astype(jnp.float32),
         causal=True, window=window)
     out = fa_ops.flash_attention(q, k, v, causal=True, window=window,
-                                 bq=32, bk=32, interpret=True)
+                                 bq=32, bk=32)
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=tol, atol=tol)
@@ -125,7 +125,7 @@ def test_flash_attention_block_sizes(bq, bk):
     k = jax.random.normal(ks[1], (1, 128, 2, 16))
     v = jax.random.normal(ks[2], (1, 128, 2, 16))
     ref = fa_ref.attention_ref(q, k, v, causal=True)
-    out = fa_ops.flash_attention(q, k, v, bq=bq, bk=bk, interpret=True)
+    out = fa_ops.flash_attention(q, k, v, bq=bq, bk=bk)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -144,8 +144,7 @@ else:
         k = jax.random.normal(ks[1], (1, s, h, 8))
         v = jax.random.normal(ks[2], (1, s, h, 8))
         ref = fa_ref.attention_ref(q, k, v, causal=True, window=window)
-        out = fa_ops.flash_attention(q, k, v, window=window, bq=16, bk=16,
-                                     interpret=True)
+        out = fa_ops.flash_attention(q, k, v, window=window, bq=16, bk=16)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=3e-5, atol=3e-5)
 
@@ -160,13 +159,11 @@ def test_flash_attention_gqa_no_repeat_bitwise(h, kv):
     q = jax.random.normal(ks[0], (2, 64, h, 16))
     k = jax.random.normal(ks[1], (2, 64, kv, 16))
     v = jax.random.normal(ks[2], (2, 64, kv, 16))
-    grouped = fa_ops.flash_attention(q, k, v, causal=True, bq=32, bk=32,
-                                     interpret=True)
+    grouped = fa_ops.flash_attention(q, k, v, causal=True, bq=32, bk=32)
     rep = h // kv
     repeated = fa_ops.flash_attention(q, jnp.repeat(k, rep, axis=2),
                                       jnp.repeat(v, rep, axis=2),
-                                      causal=True, bq=32, bk=32,
-                                      interpret=True)
+                                      causal=True, bq=32, bk=32)
     assert np.array_equal(np.asarray(grouped), np.asarray(repeated))
 
 
@@ -176,7 +173,7 @@ def test_flash_attention_rejects_indivisible_heads():
     k = jax.random.normal(ks[1], (1, 32, 2, 8))
     v = jax.random.normal(ks[2], (1, 32, 2, 8))
     with pytest.raises(ValueError):
-        fa_ops.flash_attention(q, k, v, interpret=True)
+        fa_ops.flash_attention(q, k, v)
 
 
 def test_flash_matches_model_attention_path():

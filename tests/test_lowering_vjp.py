@@ -58,8 +58,7 @@ def test_custom_vjp_matches_xla_autodiff_per_layer(x_shape, w_shape, stride):
         lambda x, w: lc_ops.lowering_conv_xla(x, w, stride=stride)),
         (0, 1))(x, w)
     got_pal = jax.grad(loss(
-        lambda x, w: lc_ops.lowering_conv(x, w, stride=stride, bp=2, rb=3,
-                                          interpret=True)), (0, 1))(x, w)
+        lambda x, w: lc_ops.lowering_conv(x, w, stride=stride, bp=2, rb=3)), (0, 1))(x, w)
     for got, name in ((got_xla, "xla"), (got_pal, "pallas")):
         assert _rel_err(got[0], ref[0]) <= 1e-5, (name, "dx")
         assert _rel_err(got[1], ref[1]) <= 1e-5, (name, "dw")
@@ -213,13 +212,11 @@ def test_bwd_kernels_match_xla_forms():
     d_hat = lower(x, 3, 3, stride)
     dw_ref = bwd.wgrad_xla(d_hat, dy, w.shape)
     lowered = d_hat.reshape(4, ho, ho, -1)
-    dw_pal = bwd.wgrad_pallas(lowered, dy, w.shape, bp=2, rb=2,
-                              interpret=True)
+    dw_pal = bwd.wgrad_pallas(lowered, dy, w.shape, bp=2, rb=2)
     np.testing.assert_allclose(np.asarray(dw_pal), np.asarray(dw_ref),
                                rtol=2e-5, atol=2e-5)
     dx_ref = bwd.dgrad_xla(dy, w, x.shape, stride)
-    dx_pal = bwd.dgrad_pallas(dy, w, x.shape, stride=stride, bp=2,
-                              interpret=True)
+    dx_pal = bwd.dgrad_pallas(dy, w, x.shape, stride=stride, bp=2)
     np.testing.assert_allclose(np.asarray(dx_pal), np.asarray(dx_ref),
                                rtol=2e-5, atol=2e-5)
 
