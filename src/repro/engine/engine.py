@@ -78,10 +78,11 @@ class Engine:
     the reference path (``engine.spmd`` module doc).
 
     ``tracer``: an ``obs.spans`` tracer recording the engine's phase
-    spans (run / data_wait / dispatch / block_until_ready / checkpoint,
-    plus per-bucket exchange annotations on the SPMD path). Defaults to
-    the tracer installed via ``obs.spans.install()`` at construction
-    time — a shared no-op when none is.
+    spans (run / data_wait / dispatch, split into prepare (the group
+    split) and launch (the jitted ``train_step``) / block_until_ready /
+    checkpoint, plus per-bucket exchange annotations on the SPMD path).
+    Defaults to the tracer installed via ``obs.spans.install()`` at
+    construction time — a shared no-op when none is.
     """
 
     def __init__(self, loss_fn: Callable, *, strategy: str = "grouped-fused",
@@ -335,10 +336,18 @@ class Engine:
                 self._annotate_buckets(built, params)
                 with tracer.span("engine.step", step=i, mode=built.mode):
                     with tracer.span("engine.dispatch"):
-                        params, mom, loss = built(params, mom, batch)
+                        with tracer.span("engine.prepare"):
+                            dbatch = built.prepare(batch)
+                        with tracer.span("engine.launch"):
+                            params, mom, loss = built.launch(params, mom,
+                                                             dbatch)
+                            # the step owns the split batch now: holding
+                            # it until the next round's split would keep
+                            # two batches on the device
+                            del dbatch
                     with tracer.span("engine.block_until_ready"):
                         # syncs: step wall ends here
-                        losses.append(float(loss))
+                        losses.append(float(built.scalar_loss(loss)))
                 t_done = timing.monotonic()
                 self.telemetry.record(step_s=t_done - t_ready,
                                       data_s=t_ready - t_prev)

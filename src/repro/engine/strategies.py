@@ -27,6 +27,7 @@ from repro.core.async_sgd import delayed_sgd_run, make_grouped_train_step
 from repro.core.compute_groups import group_batch_split
 from repro.engine.spmd import (device_batch_split, make_reference_grouped_step,
                                make_spmd_grouped_step)
+from repro.obs import spans
 
 _REGISTRY: Dict[str, "Strategy"] = {}
 
@@ -94,11 +95,16 @@ class _BuiltStep:
             return loss
         return np.asarray(loss, np.float64).mean()
 
-    def __call__(self, params, mom, batch):
+    def launch(self, params, mom, device_batch):
+        """The compiled step on an already prepared batch; the loss is
+        returned unreduced (``scalar_loss`` reduces it)."""
         place = getattr(self.raw, "place", None)     # spmd: onto the mesh
         if place is not None:
             params, mom = place(params, mom)
-        params, mom, loss = self.fn(params, mom, self.prepare(batch))
+        return self.fn(params, mom, device_batch)
+
+    def __call__(self, params, mom, batch):
+        params, mom, loss = self.launch(params, mom, self.prepare(batch))
         return params, mom, self.scalar_loss(loss)
 
     def protected_call(self, params, mom, batch):
@@ -146,7 +152,8 @@ class GroupedStrategy(Strategy):
                     gb = device_batch_split(gb, k)
                 return gb
 
-            fn = jax.jit(raw, donate_argnums=(0, 1) if donate else ())
+            fn = jax.jit(spans.named(raw, "train_step"),
+                         donate_argnums=(0, 1) if donate else ())
         return _BuiltStep(fn, raw, prepare, mode, g, k, donating=donate)
 
     def run_stacked(self, engine, params, batches, *, g, lr, momentum):

@@ -19,7 +19,11 @@ Two tracer implementations share one interface:
 - ``Tracer``: records ``SpanRecord``s. Nesting depth and parent linkage
   come from a per-thread stack (``threading.local``), so concurrently
   tracing threads (prefetch, probes) never corrupt each other's tree;
-  the finished-record list is guarded by a lock.
+  the finished-record list is guarded by a lock. While a span is open it
+  also holds a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  profile taken with a tracer installed shows the program's spans beside
+  the device ops, on the profiler's clock. The annotation sits inside the
+  span's two clock reads: a span's duration includes its cost.
 
 Usage::
 
@@ -33,6 +37,7 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from contextlib import contextmanager
 from typing import Callable, Dict, Optional, Tuple
@@ -43,6 +48,21 @@ def _default_clock() -> Callable[[], float]:
     # (engine.timing imports obs.metrics for the Telemetry facade)
     from repro.engine.timing import monotonic
     return monotonic
+
+
+def _trace_annotation():
+    # lazy: importing obs must not import jax
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the program name ``name``: ``jax.jit(named(f, "x"))``
+    compiles as ``jit_x`` and shows so in a profile, where a bare
+    ``functools.partial`` shows as ``jit__unknown``."""
+    fn = functools.partial(fn)
+    fn.__name__ = name
+    return fn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +117,8 @@ class NullTracer:
 
 class _Span:
     """Context manager recording one interval on the owning tracer."""
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_parent")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_parent",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -116,10 +137,13 @@ class _Span:
         self._parent = stack[-1] if stack else None
         stack.append(tr._reserve())
         self._t0 = tr._clock()
+        self._ann = tr._annotation(self.name)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
+        self._ann.__exit__(None, None, None)
         t1 = tr._clock()
         index = tr._stack().pop()
         tr._commit(SpanRecord(
@@ -137,6 +161,7 @@ class Tracer:
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
         self._clock = clock if clock is not None else _default_clock()
+        self._annotation = _trace_annotation()
         self._lock = threading.Lock()
         self._records: list = []
         self._next = 0

@@ -119,7 +119,10 @@ def sample_requests(trace: EventTrace, cfg: ArchConfig, *,
 @dataclasses.dataclass
 class ServeReport:
     """Per-request accounting for one serving run (times in seconds on the
-    run's virtual clock; latency = finish - arrival)."""
+    run's virtual clock; latency = finish - arrival). ``token_times[rid]``
+    holds, per output token, the clock reading taken when the call that
+    produced it (prefill or decode step) had synced: its first entry is
+    the prefill's end, its last the request's finish."""
     mode: str
     rids: np.ndarray
     arrivals: np.ndarray
@@ -127,6 +130,7 @@ class ServeReport:
     latencies: np.ndarray
     gen_counts: np.ndarray
     tokens: Dict[int, np.ndarray]
+    token_times: Dict[int, np.ndarray]
     makespan: float
     occupancy_mean: float
 
@@ -290,9 +294,9 @@ class ContinuousServer:
         capacity — the bitwise baseline). One entry per ladder rung."""
         fn = self._step_cache.get(gather_pages)
         if fn is None:
-            fn = jax.jit(
+            fn = jax.jit(spans.named(
                 functools.partial(self._step_impl, gather_pages=gather_pages),
-                donate_argnums=(1,))
+                "serve_decode_step"), donate_argnums=(1,))
             self._step_cache[gather_pages] = fn
         return fn
 
@@ -325,9 +329,9 @@ class ContinuousServer:
         key = (Pb, self._prefill_gather(Pb))
         fn = self._prefill_cache.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = jax.jit(spans.named(
                 functools.partial(self._prefill_impl, gather_pages=key[1]),
-                donate_argnums=(1,))
+                "serve_prefill"), donate_argnums=(1,))
             self._prefill_cache[key] = fn
         return fn
 
@@ -363,7 +367,10 @@ class ContinuousServer:
             jax.block_until_ready(toks)
 
     def run(self, requests: Sequence[Request]) -> ServeReport:
-        """Serve every request; returns per-request accounting."""
+        """Serve every request; returns per-request accounting. Each
+        phase of the loop (admit, prefill, schedule, decode step, emit)
+        runs in an ``obs.spans`` span, so a trace puts the loop's whole
+        time down to one of them (docs/serving.md)."""
         cfg, spec, alloc = self.cfg, self.spec, self.alloc
         S = spec.num_slots
         cap = spec.seq_capacity
@@ -374,8 +381,8 @@ class ContinuousServer:
         step_s = reg.series("serving.decode_step_s")
         latency_s = reg.series("serving.latency_s")
         occupancy = reg.series("serving.occupancy")
-        occ_gauge = reg.gauge("serving.batch_occupancy")
-        pages_gauge = reg.gauge("serving.pages_in_use")
+        prompt_ctr = reg.counter("serving.prefill_tokens")
+        lane_ctr = reg.counter("serving.prefill_lane_tokens")
         done_ctr = reg.counter("serving.requests_completed")
         tok_ctr = reg.counter("serving.tokens_generated")
 
@@ -393,6 +400,7 @@ class ContinuousServer:
         slot_left = np.zeros(S, np.int64)      # decode steps remaining
         slot_pf_end = np.zeros(S, np.float64)  # prefill end (virtual clock)
         out_tokens: Dict[int, List[int]] = {}
+        out_times: Dict[int, List[float]] = {}
         finished: Dict[int, dict] = {}
 
         t0 = monotonic()
@@ -426,44 +434,50 @@ class ContinuousServer:
                 tnow = now()
 
             # -- admission: fill free slots from the arrived queue --------
-            admits: List[int] = []
-            for s in range(S):
-                if qi >= len(reqs) or slot_req[s] is not None:
-                    continue
-                r = reqs[qi]
-                need = min(len(r.prompt), cap)
-                if r.arrival > tnow or not alloc.can_fit(need):
-                    if (n_active == 0 and not admits
-                            and r.arrival <= tnow):
-                        raise RuntimeError(
-                            f"request {r.rid} cannot fit an empty pool")
-                    break
-                alloc.ensure(s, need)
-                slot_req[s] = r
-                slot_pos[s] = 0
-                slot_left[s] = r.gen
-                out_tokens[r.rid] = []
-                finished[r.rid] = {"queue_wait": tnow - r.arrival}
-                queue_wait.append(tnow - r.arrival, step=r.rid)
-                admits.append(s)
-                qi += 1
-                n_active += 1
+            with spans.span("serve.admit"):
+                admits: List[int] = []
+                for s in range(S):
+                    if qi >= len(reqs) or slot_req[s] is not None:
+                        continue
+                    r = reqs[qi]
+                    need = min(len(r.prompt), cap)
+                    if r.arrival > tnow or not alloc.can_fit(need):
+                        if (n_active == 0 and not admits
+                                and r.arrival <= tnow):
+                            raise RuntimeError(
+                                f"request {r.rid} cannot fit an empty pool")
+                        break
+                    alloc.ensure(s, need)
+                    slot_req[s] = r
+                    slot_pos[s] = 0
+                    slot_left[s] = r.gen
+                    out_tokens[r.rid] = []
+                    out_times[r.rid] = []
+                    finished[r.rid] = {"queue_wait": tnow - r.arrival}
+                    queue_wait.append(tnow - r.arrival, step=r.rid)
+                    admits.append(s)
+                    qi += 1
+                    n_active += 1
+                if admits:
+                    plens = np.array([len(slot_req[s].prompt) if slot_req[s]
+                                      else 0 for s in range(S)], np.int32)
+                    pmax = max(len(slot_req[s].prompt) for s in admits)
+                    Pb = _bucket(pmax, cap if self.window is None else None)
+                    prompts = np.zeros((S, Pb), np.int32)
+                    admit = np.zeros(S, bool)
+                    for s in admits:
+                        r = slot_req[s]
+                        prompts[s, :len(r.prompt)] = r.prompt[:Pb]
+                        admit[s] = True
+                    prompt_ctr.inc(int(plens[admit].sum()))
+                    lane_ctr.inc(S * Pb)
+                    admit_rids = [slot_req[s].rid for s in admits]
 
             # -- prefill the admitted slots (one bucketed jitted call) ----
             if admits:
-                plens = np.array([len(slot_req[s].prompt) if slot_req[s]
-                                  else 0 for s in range(S)], np.int32)
-                pmax = max(len(slot_req[s].prompt) for s in admits)
-                Pb = _bucket(pmax, cap if self.window is None else None)
-                prompts = np.zeros((S, Pb), np.int32)
-                admit = np.zeros(S, bool)
-                for s in admits:
-                    r = slot_req[s]
-                    prompts[s, :len(r.prompt)] = r.prompt[:Pb]
-                    admit[s] = True
                 tpf = now()
                 with spans.span("serve.prefill", lanes=len(admits),
-                                bucket=Pb):
+                                bucket=Pb, rids=admit_rids):
                     fn = self._prefill_fn(Pb)
                     self.pages, toks = fn(
                         self.params, self.pages, jnp.asarray(alloc.tables),
@@ -471,52 +485,59 @@ class ContinuousServer:
                         jnp.asarray(admit))
                     toks = np.asarray(toks)        # (Pb, S); sync
                 tnow = now()
-                for s in admits:
-                    r = slot_req[s]
-                    prefill_s.append(tnow - tpf, step=r.rid)
-                    slot_pf_end[s] = tnow
-                    first = int(toks[len(r.prompt) - 1, s])
-                    out_tokens[r.rid].append(first)
-                    tok_ctr.inc()
-                    slot_tok[s] = first
-                    slot_pos[s] = len(r.prompt)
-                    slot_left[s] = r.gen - 1
-                    if slot_left[s] == 0:
-                        retire(s, tnow)
+                with spans.span("serve.emit"):
+                    for s in admits:
+                        r = slot_req[s]
+                        prefill_s.append(tnow - tpf, step=r.rid)
+                        slot_pf_end[s] = tnow
+                        first = int(toks[len(r.prompt) - 1, s])
+                        out_tokens[r.rid].append(first)
+                        out_times[r.rid].append(tnow)
+                        tok_ctr.inc()
+                        slot_tok[s] = first
+                        slot_pos[s] = len(r.prompt)
+                        slot_left[s] = r.gen - 1
+                        if slot_left[s] == 0:
+                            retire(s, tnow)
 
             if n_active == 0:
                 continue
 
             # -- one continuous decode step over every live slot ----------
-            active = np.array([r is not None for r in slot_req])
-            for s in np.nonzero(active)[0]:
-                alloc.ensure(int(s), int(slot_pos[s]) + 1)
-            occ_samples.append(int(active.sum()))
-            occupancy.append(int(active.sum()), step=steps)
-            occ_gauge.set(int(active.sum()))
-            pages_gauge.set(alloc.pages_in_use)
-            gp = self._gather_bucket(slot_pos, active)
+            with spans.span("serve.schedule"):
+                active = np.array([r is not None for r in slot_req])
+                for s in np.nonzero(active)[0]:
+                    alloc.ensure(int(s), int(slot_pos[s]) + 1)
+                occ_samples.append(int(active.sum()))
+                occupancy.append(int(active.sum()), step=steps)
+                gp = self._gather_bucket(slot_pos, active)
             tstep = now()
             with spans.span("serve.decode_step", occupancy=int(active.sum()),
                             gather=(gp if gp is not None
                                     else spec.pages_per_slot)):
-                tok, self.pages = self._step_fn(gp)(
-                    self.params, self.pages, jnp.asarray(alloc.tables),
-                    jnp.asarray(slot_tok[:, None]), jnp.asarray(slot_pos),
-                    jnp.asarray(active))
-                tok = np.asarray(tok)              # sync
+                with spans.span("serve.decode.inputs"):
+                    inputs = (jnp.asarray(alloc.tables),
+                              jnp.asarray(slot_tok[:, None]),
+                              jnp.asarray(slot_pos), jnp.asarray(active))
+                with spans.span("serve.decode.launch"):
+                    tok, self.pages = self._step_fn(gp)(
+                        self.params, self.pages, *inputs)
+                with spans.span("serve.decode.sync"):
+                    tok = np.asarray(tok)
             tnow = now()
-            step_s.append(tnow - tstep, step=steps)
-            steps += 1
-            for s in np.nonzero(active)[0]:
-                r = slot_req[s]
-                out_tokens[r.rid].append(int(tok[s]))
-                tok_ctr.inc()
-                slot_tok[s] = int(tok[s])
-                slot_pos[s] += 1
-                slot_left[s] -= 1
-                if slot_left[s] == 0:
-                    retire(int(s), tnow)
+            with spans.span("serve.emit"):
+                step_s.append(tnow - tstep, step=steps)
+                steps += 1
+                for s in np.nonzero(active)[0]:
+                    r = slot_req[s]
+                    out_tokens[r.rid].append(int(tok[s]))
+                    out_times[r.rid].append(tnow)
+                    tok_ctr.inc()
+                    slot_tok[s] = int(tok[s])
+                    slot_pos[s] += 1
+                    slot_left[s] -= 1
+                    if slot_left[s] == 0:
+                        retire(int(s), tnow)
 
         rids = np.array(sorted(finished), np.int64)
         occ = np.array(occ_samples) if occ_samples else np.zeros(1)
@@ -528,6 +549,8 @@ class ContinuousServer:
             latencies=np.array([finished[r]["latency"] for r in rids]),
             gen_counts=np.array([finished[r]["gen"] for r in rids]),
             tokens={r: np.array(out_tokens[r], np.int32) for r in rids},
+            token_times={r: np.array(out_times[r], np.float64)
+                         for r in rids},
             makespan=now(),
             occupancy_mean=float(occ.mean()))
 
@@ -573,6 +596,7 @@ def static_serve_trace(cfg: ArchConfig, requests: Sequence[Request], *,
 
     finished: Dict[int, dict] = {}
     tokens: Dict[int, np.ndarray] = {}
+    token_times: Dict[int, np.ndarray] = {}
     t0 = monotonic()
     voff = 0.0
     now = lambda: monotonic() - t0 + voff
@@ -597,7 +621,8 @@ def static_serve_trace(cfg: ArchConfig, requests: Sequence[Request], *,
         tpf = now()
         logits, cache = jax.block_until_ready(
             pf(params, cache, jnp.asarray(prompts)))
-        prefill_s.append(now() - tpf)
+        times = [now()]
+        prefill_s.append(times[0] - tpf)
         tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
         outs = [np.asarray(tok)[:, 0]]
         for t in range(pmax, pmax + gmax - 1):
@@ -606,7 +631,8 @@ def static_serve_trace(cfg: ArchConfig, requests: Sequence[Request], *,
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
             tok = tok.astype(jnp.int32)
             outs.append(np.asarray(tok)[:, 0])     # sync
-            step_s.append(now() - ts)
+            times.append(now())
+            step_s.append(times[-1] - ts)
         end = now()
         occ_num += len(grp) * (end - start)
         occ_time += end - start
@@ -618,6 +644,7 @@ def static_serve_trace(cfg: ArchConfig, requests: Sequence[Request], *,
                                "gen": r.gen}
             latency_s.append(end - r.arrival, step=r.rid)
             tokens[r.rid] = allt[i, :r.gen].astype(np.int32)
+            token_times[r.rid] = np.array(times[:r.gen], np.float64)
 
     rids = np.array(sorted(finished), np.int64)
     makespan = now()
@@ -629,5 +656,6 @@ def static_serve_trace(cfg: ArchConfig, requests: Sequence[Request], *,
         latencies=np.array([finished[r]["latency"] for r in rids]),
         gen_counts=np.array([finished[r]["gen"] for r in rids]),
         tokens=tokens,
+        token_times=token_times,
         makespan=makespan,
         occupancy_mean=occ_num / occ_time / batch if occ_time else 0.0)

@@ -8,6 +8,7 @@ end-to-end, the HE x SE report closing within the CI tolerance, the
 bench env stamp + compare.py's --normalize refusal, and the validate
 CLI the bench-smoke job gates on.
 """
+import functools
 import json
 import threading
 
@@ -80,6 +81,49 @@ def test_null_tracer_is_shared_noop():
         sp.set(anything=True)
     assert null.records() == ()
     assert null.instant("c") is None
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit by name."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_tracer_spans_hold_profiler_annotations(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotations)
+    monkeypatch.setattr(_Annotations, "log", [])
+    tr = spans.Tracer()
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    tr.instant("mark")
+    assert _Annotations.log == [("enter", "outer"), ("enter", "inner"),
+                                ("exit", "inner"), ("exit", "outer")]
+    with pytest.raises(RuntimeError):
+        with tr.span("failing"):
+            raise RuntimeError("boom")
+    assert _Annotations.log[-1] == ("exit", "failing")
+    null = spans.NullTracer()
+    with null.span("quiet"):
+        pass
+    null.instant("quiet")
+    assert len(_Annotations.log) == 6     # the null tracer opens none
+
+
+def test_named_programs_compile_under_their_name():
+    f = jax.jit(spans.named(functools.partial(lambda x, k: x * k, k=2.0),
+                            "scaled"))
+    assert "module @jit_scaled " in f.lower(jnp.ones(3)).as_text()
+    assert float(f(jnp.ones(3))[0]) == 2.0
 
 
 def test_install_and_maybe_traced_restore():
@@ -303,9 +347,14 @@ def test_engine_run_emits_phase_spans_and_metrics():
     eng = _run_engine(tr)
     names = set(tr.span_names())
     expected = {"engine.run", "engine.step", "engine.data_wait",
-                "engine.dispatch", "engine.block_until_ready",
-                "engine.build_step", "data.h2d"}
+                "engine.dispatch", "engine.prepare", "engine.launch",
+                "engine.block_until_ready", "engine.build_step", "data.h2d"}
     assert expected <= names, f"missing {expected - names}"
+    # the dispatch is split into the group split and the step's launch
+    by_index = {r.index: r for r in tr.records()}
+    for r in tr.records():
+        if r.name in ("engine.prepare", "engine.launch"):
+            assert by_index[r.parent].name == "engine.dispatch"
     if jax.device_count() >= 2:          # tier-1 forces the 8-device lane
         assert "exchange.bucket" in names
         buckets = [r for r in tr.records() if r.name == "exchange.bucket"]
@@ -322,6 +371,20 @@ def test_engine_run_emits_phase_spans_and_metrics():
     # per-step nesting: 6 data_wait + 6 step spans under one run span
     per = [r for r in tr.records() if r.name == "engine.data_wait"]
     assert len(per) == 6
+
+
+def test_engine_step_compiles_as_train_step():
+    wl = mlp_classify()
+    eng = Engine(wl.loss_fn, num_groups=2, lr=0.05, momentum=0.3)
+    params = wl.init(jax.random.PRNGKey(0))
+    mom = jax.tree.map(jnp.zeros_like, params)
+    batch = jax.tree.map(lambda x: x[0], wl.sample_batches(
+        jax.random.PRNGKey(1), 1, 32))
+    built = eng._built_step(eng.strategy, g=2, lr=eng.lr,
+                            momentum=eng.momentum,
+                            per_group_batch=eng._per_group_batch(2, 32))
+    text = built.fn.lower(params, mom, built.prepare(batch)).as_text()
+    assert "module @jit_train_step " in text
 
 
 def test_engine_untraced_records_no_spans_and_same_metrics():

@@ -215,8 +215,12 @@ def test_static_baseline_accounts_every_request():
     rep = static_serve_trace(cfg, reqs, batch=2, params=params)
     assert len(rep.rids) == len(reqs)
     assert rep.total_tokens == sum(r.gen for r in reqs)
+    waits = dict(zip(rep.rids, rep.queue_waits))
     for r in reqs:
         assert len(rep.tokens[r.rid]) == r.gen
+        tt = rep.token_times[r.rid]
+        assert len(tt) == r.gen and (np.diff(tt) > 0).all()
+        assert tt[0] >= r.arrival + waits[r.rid]
     # group members share a finish time; latency is sorted by arrival wait
     assert (rep.latencies > 0).all()
     assert 0 < rep.occupancy_mean <= 1.0
@@ -425,6 +429,111 @@ def test_serving_metrics_land_in_registry():
     assert reg.counter("serving.requests_completed").value == 3
     assert reg.counter("serving.tokens_generated").value == \
         sum(r.gen for r in reqs)
+
+
+# ---------------------------------------------------------------------------
+# the server loop under spans: coverage, token times, counters, names
+# ---------------------------------------------------------------------------
+
+_LOOP_SPANS = ("serve.admit", "serve.prefill", "serve.emit",
+               "serve.schedule", "serve.decode_step")
+
+
+def _traced_run(prefill_mode="scan"):
+    from repro.engine.timing import monotonic
+    from repro.obs import spans
+    from repro.obs.metrics import MetricRegistry
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    reqs = sample_requests(poisson_trace(40.0, 12, seed=3), cfg,
+                           prompt_range=(4, 12), gen_range=(3, 8), seed=3)
+    srv = ContinuousServer(cfg, params, slots=2, page_size=4, max_seq=32,
+                           prefill_mode=prefill_mode)
+    srv.warmup([len(r.prompt) for r in reqs])
+    reg = MetricRegistry()
+    srv.reset(registry=reg)
+    tracer = spans.Tracer()
+    with spans.install(tracer):
+        t0 = monotonic()
+        srv.run(reqs)
+        wall = monotonic() - t0
+    return srv, reqs, reg, tracer.records(), wall
+
+
+@pytest.mark.parametrize("prefill_mode", ["scan", "parallel"])
+def test_server_loop_spans_cover_the_run(prefill_mode):
+    srv, reqs, reg, recs, wall = _traced_run(prefill_mode)
+    top = [r for r in recs if r.depth == 0]
+    assert {r.name for r in top} == set(_LOOP_SPANS)
+    assert sum(r.duration_s for r in top) >= 0.9 * wall
+    # every decode step splits into its inputs, launch and sync
+    by_index = {r.index: r for r in recs}
+    steps = [r for r in recs if r.name == "serve.decode_step"]
+    for name in ("serve.decode.inputs", "serve.decode.launch",
+                 "serve.decode.sync"):
+        kids = [r for r in recs if r.name == name]
+        assert len(kids) == len(steps)
+        assert all(by_index[k.parent].name == "serve.decode_step"
+                   for k in kids)
+    # a request's prefill span names it
+    pf = [r for r in recs if r.name == "serve.prefill"]
+    assert sorted(rid for r in pf for rid in r.attrs["rids"]) == \
+        sorted(r.rid for r in reqs)
+    # the counters: real prompt tokens, and slots x bucket per call
+    assert reg.counter("serving.prefill_tokens").value == \
+        sum(len(r.prompt) for r in reqs)
+    assert reg.counter("serving.prefill_lane_tokens").value == \
+        sum(srv.spec.num_slots * r.attrs["bucket"] for r in pf)
+    # the per-step gauges that nothing read are gone
+    assert not {"serving.batch_occupancy", "serving.pages_in_use"} & \
+        set(reg.names())
+
+
+def test_token_times_agree_with_the_servers_records(monkeypatch):
+    """On a clock that moves one millisecond a reading, each request's
+    token times hold exactly what the server's other records say: the
+    first token lands one reading after admission plus the queue wait
+    and the prefill call, the last at the end of its decode."""
+    import repro.serving.engine as E
+    tick = iter(range(1, 1 << 30))
+    monkeypatch.setattr(E, "monotonic", lambda: next(tick) * 1e-3)
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    reqs = sample_requests(poisson_trace(40.0, 8, seed=3), cfg,
+                           prompt_range=(4, 12), gen_range=(3, 8), seed=3)
+    srv = ContinuousServer(cfg, params, slots=2, page_size=4, max_seq=32)
+    rep = srv.run(reqs)
+    reg = srv.registry
+
+    def by_rid(name):
+        s = reg.series(name)
+        return dict(zip(s.steps, s.values))
+    pf, dec = by_rid("serving.prefill_s"), by_rid("serving.decode_s")
+    for rid, arr, qw, gen in zip(rep.rids, rep.arrivals, rep.queue_waits,
+                                 rep.gen_counts):
+        tt = rep.token_times[rid]
+        assert len(tt) == gen and (np.diff(tt) > 0).all()
+        np.testing.assert_allclose(tt[0] - arr, qw + pf[rid] + 1e-3,
+                                   rtol=0, atol=1e-9)
+        np.testing.assert_allclose(tt[-1] - tt[0], dec[rid], rtol=0,
+                                   atol=1e-9)
+
+
+def test_server_programs_compile_under_their_names():
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    srv = ContinuousServer(cfg, params, slots=2, page_size=4, max_seq=16,
+                           window=None, prefill_mode="parallel")
+    S = srv.spec.num_slots
+    table = jnp.asarray(srv.alloc.tables)
+    off, inact = jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool)
+    step = srv._step_fn(None).lower(srv.params, srv.pages, table,
+                                    jnp.zeros((S, 1), jnp.int32), off, inact)
+    prefill = srv._prefill_fn(8).lower(srv.params, srv.pages, table,
+                                       jnp.zeros((S, 8), jnp.int32), off,
+                                       inact)
+    assert "module @jit_serve_decode_step " in step.as_text()
+    assert "module @jit_serve_prefill " in prefill.as_text()
 
 
 # ---------------------------------------------------------------------------
