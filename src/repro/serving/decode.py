@@ -17,6 +17,13 @@ at the reserved scratch page 0, so colliding scatter indices always carry
 identical payloads and the step stays deterministic as requests join and
 leave the batch — one compiled step, any population.
 
+The whole ``(L, P, page, K, hd)`` pools ride the layer scan's carry, and
+each layer scatters its B new rows into them in place at ``[l, pid,
+in_page]``. Passed as the scan's xs and returned as its ys instead, every
+step would slice each layer's pool out of the input, write it whole into
+a fresh stacked ys buffer and copy that into the (donated) output: three
+passes over the entire pool to store one token per slot per layer.
+
 Attention implementations (``attn_impl``):
 
 - ``"pallas"`` — the in-kernel paged flash-decode
@@ -54,23 +61,24 @@ from repro.models import moe as M
 ATTN_IMPLS = ("xla", "pallas", "pallas_gather")
 
 
-def paged_attention_decode(p, x, k_pages, v_pages, table, pos, active,
+def paged_attention_decode(p, x, k_pool, v_pool, layer, table, pos, active,
                            cfg: ArchConfig, *, window: Optional[int] = None,
                            attn_impl: str = "xla",
                            gather_pages: Optional[int] = None):
     """One layer's decode over the paged pool.
 
-    x: (B,1,D) hidden; k_pages/v_pages: (P, page, K, hd) this layer's pool;
-    table: (B, max_pages) int32 page ids (0 = scratch); pos: (B,) int32
-    absolute position per slot; active: (B,) bool live-request mask.
+    x: (B,1,D) hidden; k_pool/v_pool: (L, P, page, K, hd) every layer's
+    pool; layer: int32 scalar, this layer's index into them; table: (B,
+    max_pages) int32 page ids (0 = scratch); pos: (B,) int32 absolute
+    position per slot; active: (B,) bool live-request mask.
     ``gather_pages`` (static, XLA path only): gather just the first
     ``gather_pages`` table columns — must cover every live row's pages
     (the server's bucket ladder guarantees it).
-    Returns (out (B,1,D), (k_pages, v_pages)).
+    Returns (out (B,1,D), (k_pool, v_pool)) with this layer's rows written.
     """
     cd = cfg.dtype("compute")
     B = x.shape[0]
-    _, page, K, hd = k_pages.shape
+    _, _, page, K, hd = k_pool.shape
     max_pages = table.shape[1]
     W = max_pages * page
 
@@ -84,25 +92,27 @@ def paged_attention_decode(p, x, k_pages, v_pages, table, pos, active,
     in_page = slot % page
     pid = jnp.take_along_axis(table, page_idx[:, None], axis=1)[:, 0]  # (B,)
 
-    kn = k[:, 0].astype(k_pages.dtype)               # (B, K, hd)
-    vn = v[:, 0].astype(v_pages.dtype)
+    kn = k[:, 0].astype(k_pool.dtype)                # (B, K, hd)
+    vn = v[:, 0].astype(v_pool.dtype)
     act = active[:, None, None]
-    oldk = k_pages[pid, in_page]
-    oldv = v_pages[pid, in_page]
-    k_pages = k_pages.at[pid, in_page].set(jnp.where(act, kn, oldk))
-    v_pages = v_pages.at[pid, in_page].set(jnp.where(act, vn, oldv))
+    oldk = k_pool[layer, pid, in_page]
+    oldv = v_pool[layer, pid, in_page]
+    k_pool = k_pool.at[layer, pid, in_page].set(jnp.where(act, kn, oldk))
+    v_pool = v_pool.at[layer, pid, in_page].set(jnp.where(act, vn, oldv))
 
     if attn_impl == "pallas":
         from repro.kernels.paged_attention import ops as pa_ops
-        out = pa_ops.paged_attention(q, k_pages, v_pages, table, pos,
-                                     window=window)
+        out = pa_ops.paged_attention(
+            q, jax.lax.dynamic_index_in_dim(k_pool, layer, keepdims=False),
+            jax.lax.dynamic_index_in_dim(v_pool, layer, keepdims=False),
+            table, pos, window=window)
     else:
         gp = max_pages if gather_pages is None else min(gather_pages,
                                                         max_pages)
         tb = table if gp == max_pages else table[:, :gp]
         Wb = gp * page
-        ck = k_pages[tb].reshape(B, Wb, K, hd)       # the dense ring view
-        cv = v_pages[tb].reshape(B, Wb, K, hd)
+        ck = k_pool[layer, tb].reshape(B, Wb, K, hd)  # the dense ring view
+        cv = v_pool[layer, tb].reshape(B, Wb, K, hd)
         if attn_impl == "pallas_gather" and window is None:
             from repro.kernels.flash_attention import ops as fa_ops
             out = fa_ops.flash_attention(q, ck.astype(cd), cv.astype(cd),
@@ -117,7 +127,7 @@ def paged_attention_decode(p, x, k_pages, v_pages, table, pos, active,
             w = jax.nn.softmax(scores, axis=-1).astype(cd)
             out = L._apply_scores(w, cv.astype(cd))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(cd))
-    return y, (k_pages, v_pages)
+    return y, (k_pool, v_pool)
 
 
 def paged_decode_step(params, pages, table, tokens, pos, active,
@@ -129,7 +139,8 @@ def paged_decode_step(params, pages, table, tokens, pos, active,
     pages: {"k","v"}: (L, P, page, K, hd); table: (B, max_pages) shared by
     all layers; tokens: (B,1) int32; pos: (B,) int32; active: (B,) bool.
     Returns (logits (B,1,V) fp32, new pages). Mirrors
-    ``transformer.decode_step``'s layer scan so the math bit-matches.
+    ``transformer.decode_step``'s layer scan so the math bit-matches; the
+    pools are carried through it and written in place (module docstring).
     """
     if window is None:
         window = cfg.sliding_window
@@ -141,12 +152,13 @@ def paged_decode_step(params, pages, table, tokens, pos, active,
         raise ValueError(f"paged decode supports dense/moe, not {t!r}")
     x = L.embed(params["embed"], tokens, cfg)
 
-    def body(h, xs):
-        bp, kp, vp = xs
-        a, (nkp, nvp) = paged_attention_decode(
+    def body(carry, xs):
+        h, kp, vp = carry
+        bp, layer = xs
+        a, (kp, vp) = paged_attention_decode(
             bp["attn"], L.rms_norm(h, bp["ln1"], cfg.norm_eps), kp, vp,
-            table, pos, active, cfg, window=window, attn_impl=attn_impl,
-            gather_pages=gather_pages)
+            layer, table, pos, active, cfg, window=window,
+            attn_impl=attn_impl, gather_pages=gather_pages)
         h = h + a
         h2 = L.rms_norm(h, bp["ln2"], cfg.norm_eps)
         if t == "dense":
@@ -154,10 +166,12 @@ def paged_decode_step(params, pages, table, tokens, pos, active,
         else:
             y, _ = M.moe_forward(bp["moe"], h2, cfg)
             h = h + y
-        return h, (nkp, nvp)
+        return (h, kp, vp), None
 
-    x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"],
-                                         pages["k"], pages["v"]))
+    n_layers = pages["k"].shape[0]
+    (x, nk, nv), _ = jax.lax.scan(
+        body, (x, pages["k"], pages["v"]),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["embed"], x, cfg)
     return logits, {"k": nk, "v": nv}

@@ -146,6 +146,37 @@ def test_inactive_slots_leave_scratch_page_untouched():
     assert not np.asarray(pages["v"]).any()
 
 
+def test_decode_step_writes_only_active_rows_in_place():
+    """One step over a pool full of prior content (three slots, the middle
+    one inactive) changes the pool exactly at ``(l, pid[b], in_page[b])``
+    for the active rows b and every layer l; every other element — the
+    inactive row's target included — stays bitwise what it was."""
+    cfg = _cfg()
+    B = 3
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    spec = PagedCacheSpec.for_config(cfg, num_slots=B, page_size=4,
+                                     max_seq=16)
+    alloc = _full_tables(spec)
+    rng = np.random.default_rng(4)
+    shape = (spec.num_layers, spec.num_pages, spec.page_size,
+             spec.kv_heads, spec.head_dim)
+    before = {n: rng.standard_normal(shape).astype(np.float32)
+              for n in ("k", "v")}
+    pos = np.array([5, 9, 14], np.int32)
+    active = np.array([True, False, True])
+    _, pages = paged_decode_step(
+        params, {n: jnp.asarray(a) for n, a in before.items()},
+        jnp.asarray(alloc.tables), jnp.asarray([[3], [7], [11]], jnp.int32),
+        jnp.asarray(pos), jnp.asarray(active), cfg, window=None)
+    pid = alloc.tables[np.arange(B), pos // spec.page_size]
+    written = np.zeros(shape[:3], bool)               # (L, P, page)
+    written[:, pid[active], (pos % spec.page_size)[active]] = True
+    for n in ("k", "v"):
+        after = np.asarray(pages[n])
+        assert np.array_equal(after[~written], before[n][~written]), n
+        assert (after[written] != before[n][written]).all(), n
+
+
 # ---------------------------------------------------------------------------
 # continuous batching == solo decoding, token-exact
 # ---------------------------------------------------------------------------

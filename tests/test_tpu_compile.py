@@ -13,9 +13,11 @@ lives in this one file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -101,31 +103,64 @@ def test_flash_attention_compiles(one_chip):
     _assert_kernel(compiled)
 
 
-def test_phi4_paged_decode_step_compiles(one_chip):
-    """The serving decode step of phi4-mini at full width, 2 layers, bf16
-    weights, 8 slots of 512 tokens, through the paged kernel."""
+def _compile_phi4_decode_step(one_chip, *, slots, max_seq, donate=False,
+                              **step_kw):
+    """phi4-mini's serving decode step at full width, 2 layers, bf16
+    weights, page 16, compiled for the described chip. Returns the
+    compiled step and the pool's shape."""
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.serving import PagedCacheSpec, init_pages, paged_decode_step
     cfg = dataclasses.replace(get_config("phi4-mini-3.8b"), num_layers=2,
                               param_dtype="bfloat16")
-    slots = 8
     spec = PagedCacheSpec.for_config(cfg, num_slots=slots, page_size=16,
-                                     max_seq=512)
+                                     max_seq=max_seq)
     place = lambda t: jax.tree.map(
         lambda s: _spec(one_chip, s.shape, s.dtype), t)
     params = place(jax.eval_shape(lambda k: T.init_params(k, cfg),
                                   jax.random.PRNGKey(0)))
     pages = place(jax.eval_shape(lambda: init_pages(spec)))
     f = jax.jit(lambda p, pg, tbl, tok, pos, act: paged_decode_step(
-        p, pg, tbl, tok, pos, act, cfg, attn_impl="pallas"))
+        p, pg, tbl, tok, pos, act, cfg, **step_kw),
+        donate_argnums=(1,) if donate else ())
     compiled = f.lower(params, pages,
                        _spec(one_chip, (slots, spec.pages_per_slot),
                              jnp.int32),
                        _spec(one_chip, (slots, 1), jnp.int32),
                        _spec(one_chip, (slots,), jnp.int32),
                        _spec(one_chip, (slots,), jnp.bool_)).compile()
+    return compiled, pages["k"].shape
+
+
+def test_phi4_paged_decode_step_compiles(one_chip):
+    """The serving decode step of phi4-mini at full width, 2 layers, bf16
+    weights, 8 slots of 512 tokens, through the paged kernel."""
+    compiled, _ = _compile_phi4_decode_step(one_chip, slots=8, max_seq=512,
+                                            attn_impl="pallas")
     _assert_kernel(compiled)
     mem = compiled.memory_analysis()
     # 2 layers of bf16 weights + pools fit one 16 GB chip with room
     assert mem.argument_size_in_bytes < 4 << 30
+
+
+def test_phi4_xla_decode_step_writes_pool_in_place(one_chip):
+    """The chat cell's decode step (``attn_impl="xla"``, one gather rung,
+    the pool donated as in ``ContinuousServer._step_fn``), 4 slots of
+    3,072 tokens: no instruction copies or slices out the whole pool or
+    one layer's pool, and no pool-sized temporary is left — the new rows
+    are scattered into the donated buffer in place."""
+    compiled, pool = _compile_phi4_decode_step(
+        one_chip, slots=4, max_seq=3072, donate=True, attn_impl="xla",
+        gather_pages=128)                            # (L, P, page, K, hd)
+    layer = pool[1:]
+    layer_bytes = 2 * int(np.prod(layer))           # bf16
+    shapes = {",".join(map(str, s)) for s in (pool, layer, (1,) + layer)}
+    moves = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]\S* "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and m.group(1) in shapes:
+            moves.append(line.strip()[:120])
+    assert not moves, moves
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, mem.temp_size_in_bytes
