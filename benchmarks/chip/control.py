@@ -82,7 +82,7 @@ def serve_seeds(cell, cfg, traffic, seeds, control_seeds, seconds):
             del weights
             server.params = None
             gc.collect()
-            weights = models.lm_weights(cfg, models.key_from_seed(seed))
+            weights = models.weights(cfg, models.key_from_seed(seed))
             server.params = weights
         reqs = loadgen.requests(traffic, seed, seconds, arch.vocab_size)
         if not warmed:

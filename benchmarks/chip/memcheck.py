@@ -32,6 +32,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    import families
     import loadgen
     import models
     from repro.serving import engine as E
@@ -50,11 +51,11 @@ def main(argv=None) -> int:
             a.shape, a.dtype, sharding=dev), tree)
 
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
-    arch = models.lm_program_config(cfg)
-    wshape = jax.eval_shape(lambda: models.lm_weights_fn(cfg)(
-        jax.random.PRNGKey(0)))
+    fam = families.of(cfg)
+    arch = fam.program_config(cfg)
+    wshape = jax.eval_shape(fam.weights_fn(cfg), jax.random.PRNGKey(0))
     report = {}
-    c = jax.jit(models.lm_weights_fn(cfg)).lower(key).compile()
+    c = jax.jit(fam.weights_fn(cfg)).lower(key).compile()
     report["weights"] = c.memory_analysis()
 
     real_init = E.init_pages

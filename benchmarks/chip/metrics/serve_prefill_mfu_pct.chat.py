@@ -1,7 +1,7 @@
 """Prefill's share of the chip's bf16 peak: 2 FLOPs per matmul parameter
 per prompt token prefilled, over the device time inside the server's
 ``serve.prefill`` spans."""
-import counts
+import families
 
 
 def read(ctx):
@@ -11,5 +11,5 @@ def read(ctx):
     reqs = ctx.layer["requests"]
     done = set(int(r) for r in ctx.layer["report"].rids)
     tokens = sum(len(r.prompt) for r in reqs if r.rid in done)
-    flops = counts.lm_forward_flops_per_token(ctx.cfg) * tokens
+    flops = families.of(ctx.cfg).forward_flops_per_token(ctx.cfg) * tokens
     return 100.0 * flops / (busy * ctx.peak.bf16_flops)

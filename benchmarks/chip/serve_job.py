@@ -24,6 +24,7 @@ from typing import Dict, List
 import jax.numpy as jnp
 import numpy as np
 
+import families
 import loadgen
 import models
 from window import Window
@@ -133,8 +134,8 @@ def reference_gaps(cfg: Dict, weights, seqs: List[np.ndarray],
 
 def build(cfg: Dict, traffic: Dict, seed: int):
     from repro.serving import ContinuousServer
-    arch = models.lm_program_config(cfg)
-    weights = models.lm_weights(cfg, models.key_from_seed(seed))
+    arch = families.of(cfg).program_config(cfg)
+    weights = models.weights(cfg, models.key_from_seed(seed))
     srv = traffic["server"]
     server = ContinuousServer(arch, weights, slots=srv["slots"],
                               page_size=srv["page_size"],

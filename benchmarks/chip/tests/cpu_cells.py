@@ -1,5 +1,6 @@
-"""The cells' traffic and limits with CPU-sized stand-in configurations,
-for the tests that drive the harness without a chip."""
+"""The cells' traffic and limits with CPU-sized stand-in configurations
+(each configuration's ``cpu_stand_in``), for the tests that drive the
+harness without a chip."""
 import json
 from pathlib import Path
 
@@ -11,7 +12,6 @@ import models
 import run
 
 ROOT = Path(__file__).resolve().parents[3]
-STAND_IN = {"caffenet": "tiny-cnn", "phi4-mini-3.8b": "tiny-lm"}
 
 
 def cpu_traffic(traffic):
@@ -37,12 +37,18 @@ def cells_of_kind(kind):
             if loadgen.load_traffic(w["traffic"])["kind"] == kind]
 
 
+def stand_in(config):
+    """The CPU stand-in that configuration ``config`` names."""
+    return models.load_config(models.load_config(config)["cpu_stand_in"])
+
+
 def drive(workload, seed=2**31 + 11, seconds=1.0):
-    """One run of ``workload`` on the CPU stand-in; the result object."""
+    """One run of ``workload`` on its configuration's CPU stand-in; the
+    result object."""
     b = bench()
     cell = {w["name"]: w for w in b["workloads"]}[workload]
     limits = check.load_limits(workload)
-    cfg = models.load_config(STAND_IN[cell["config"]])
+    cfg = stand_in(cell["config"])
     traffic = cpu_traffic(loadgen.load_traffic(cell["traffic"]))
     d = jax.devices()[0]
     device = {"platform": d.platform, "kind": d.device_kind,

@@ -4,13 +4,14 @@ import jax.numpy as jnp
 import pytest
 
 import check
+import families
 import loadgen
 import models
 import train_job
-from cpu_cells import bench, cells_of_kind, drive
+from cpu_cells import bench, cells_of_kind, drive, stand_in
 
 CELLS = cells_of_kind("train")
-TRAFFIC = {w["name"]: w["traffic"] for w in bench()["workloads"]}
+CELL = {w["name"]: w for w in bench()["workloads"]}
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -25,11 +26,11 @@ def test_a_sound_run_is_correct(cell):
 @pytest.mark.parametrize("seed", [3, 2**31 + 3, 2**33 + 3])
 def test_the_control_fails(cell, seed):
     """The reference in bfloat16 put in the program's place."""
-    cfg = models.load_config("tiny-cnn")
-    traffic = loadgen.load_traffic(TRAFFIC[cell])
+    cfg = stand_in(CELL[cell]["config"])
+    traffic = loadgen.load_traffic(CELL[cell]["traffic"])
     key = models.key_from_seed(seed)
-    p0 = models.cnn_weights(cfg, key)
-    pool = models.image_pool(cfg, key, 3, traffic["global_batch"])
+    p0 = models.weights(cfg, key)
+    pool = families.of(cfg).inputs(cfg, key, 3, traffic["global_batch"])
     ref = train_job.reference_rounds(cfg, traffic, p0, pool, 3)
     ctl = train_job.reference_rounds(cfg, traffic, p0, pool, 3,
                                      dtype=jnp.bfloat16)

@@ -66,6 +66,12 @@ def test_every_cell_resolves_to_its_files(bench):
             data = json.load(f)
         assert data["name"] == c["name"] and data["reduced"] == c["reduced"]
         assert (HERE / "configs" / f"{data['reference']}.py").is_file()
+        assert (HERE / "families" / f"{data['family']}.py").is_file()
+        with open(HERE / "configs" / f"{data['cpu_stand_in']}.json") as f:
+            stand_in = json.load(f)
+        assert stand_in["name"] == data["cpu_stand_in"]
+        assert stand_in["family"] == data["family"]
+        assert stand_in["reference"] == data["reference"]
     used = set()
     for w in bench["workloads"]:
         assert w["config"] in cfgs
@@ -79,14 +85,22 @@ def test_every_cell_resolves_to_its_files(bench):
 
 
 def test_every_data_file_belongs_to_an_entry(bench):
-    """No traffic, limits or metric file beside the manifest that no cell
-    or metric of it names."""
+    """No traffic, limits, metric, configuration or family file beside the
+    manifest that no cell, metric or configuration of it names: a
+    configuration file is one of BENCHMARK.json's or the CPU stand-in of
+    one, and a family module is the family of one."""
     traffic = {w["traffic"] for w in bench["workloads"]}
     cells = {w["name"] for w in bench["workloads"]}
     metrics = {m["name"] for m in bench["per_layer"]}
     assert {p.stem for p in (HERE / "traffic").glob("*.json")} == traffic
     assert {p.stem for p in (HERE / "limits").glob("*.json")} == cells
     assert {p.stem for p in (HERE / "metrics").glob("*.py")} == metrics
+    cfgs = [json.loads((ROOT / c["file"]).read_text())
+            for c in bench["configs"]]
+    named = ({c["name"] for c in cfgs} | {c["cpu_stand_in"] for c in cfgs})
+    assert {p.stem for p in (HERE / "configs").glob("*.json")} == named
+    assert {p.stem for p in (HERE / "families").glob("*.py")
+            if p.name != "__init__.py"} == {c["family"] for c in cfgs}
 
 
 def test_each_metric_moves_a_metric_its_cells_report(bench):

@@ -19,18 +19,18 @@ import jax.numpy as jnp
 import numpy as np
 
 import check
+import families
 import models
 from window import Window
 
 
 def _engine(cfg: Dict, traffic: Dict, chips: int, tracer=None):
     from repro.engine import Engine
-    from repro.models import cnn as C
-    pcfg = models.cnn_program_config(cfg)
-    return Engine(lambda p, b: C.loss_fn(p, b, pcfg),
-                  strategy=traffic["strategy"], num_groups=traffic["groups"],
+    loss, head_filter = families.of(cfg).loss(cfg)
+    return Engine(loss, strategy=traffic["strategy"],
+                  num_groups=traffic["groups"],
                   lr=traffic["lr"], momentum=traffic["momentum"],
-                  head_filter=C.head_filter,
+                  head_filter=head_filter,
                   update_impl=traffic["update_impl"],
                   exec_mode=traffic["exec_mode"], mp=traffic.get("mp", 1),
                   num_devices=chips, tracer=tracer)
@@ -71,14 +71,14 @@ def reference_rounds(cfg: Dict, traffic: Dict, p0, pool, rounds: int,
 def setup(cfg: Dict, traffic: Dict, seed: int, chips: int, tracer=None,
           engine=None):
     key = models.key_from_seed(seed)
-    p0 = models.cnn_weights(cfg, key)
+    p0 = models.weights(cfg, key)
     if chips > 1:
         from jax.sharding import NamedSharding, PartitionSpec as P
         rep = NamedSharding(_pool_sharding(chips).mesh, P())
         p0 = jax.device_put(p0, rep)
-    pool = models.image_pool(cfg, key, traffic["pool_batches"],
-                             traffic["global_batch"],
-                             sharding=_pool_sharding(chips))
+    pool = families.of(cfg).inputs(cfg, key, traffic["pool_batches"],
+                                   traffic["global_batch"],
+                                   sharding=_pool_sharding(chips))
     engine = engine or _engine(cfg, traffic, chips, tracer)
     rounds = traffic["check_rounds"]
     p, m, prog = program_rounds(engine, p0, pool, rounds)
