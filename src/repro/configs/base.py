@@ -14,11 +14,55 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``num_experts`` are the experts held here (the expert weights'
+    leading axis), experts 0 .. ``num_experts - 1`` of the
+    ``router_experts`` the router scores (the whole layer's count; None:
+    the layer is held whole)."""
     num_experts: int
     top_k: int
     d_ff_expert: int
     num_shared_experts: int = 0
     router_aux_weight: float = 0.01
+    router_experts: Optional[int] = None
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum 1
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values are
+    decompressed per head from one ``kv_lora_rank``-wide latent row, and
+    each head's query and key carry a ``qk_rope_head_dim`` rotary part
+    beside a ``qk_nope_head_dim`` part; the rotary key is one row shared
+    by every head. No query compression (``q_lora_rank`` null)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached row: the normed latent and the rotated
+        shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling as DeepSeek-V2 applies it (arXiv:2309.00071)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +103,9 @@ class ArchConfig:
     act: str = "swiglu"           # swiglu | gelu
     sliding_window: Optional[int] = None   # set for sub-quadratic attention variant
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None       # latent attention in every layer
+    yarn: Optional[YarnConfig] = None     # scaled RoPE frequencies
+    first_k_dense: int = 0        # moe: leading layers with a dense d_ff MLP
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
     # encoder-decoder (whisper): encoder layers; frontend supplies embeddings

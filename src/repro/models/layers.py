@@ -15,7 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnConfig
 
 # Threshold at/above which train/prefill attention switches to the chunked
 # (flash-semantics) implementation to avoid materializing S^2 scores.
@@ -35,15 +35,48 @@ def init_rms_norm(d: int, dtype) -> jax.Array:
     return jnp.zeros((d,), dtype=dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., S, H, hd); positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(yarn: YarnConfig, dim: int, theta: float):
+    """(low, high): the rotary pair indices between which YaRN blends
+    interpolated into extrapolated frequencies — those that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    def pair(turns):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(pair(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair(yarn.beta_slow)), dim - 1)
+    return low, high
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn: Optional[YarnConfig] = None) -> jax.Array:
+    """Rotary embedding over the last axis, in interleaved pairs.
+    x: (..., S, H, hd); positions: (..., S). With ``yarn``, DeepSeek-V2's
+    YaRN frequencies: pairs below ``low`` keep theta's frequency, pairs
+    above ``high`` take it divided by ``factor``, a linear ramp between;
+    cos and sin scaled by mscale(mscale) / mscale(mscale_all_dim)."""
     if theta <= 0.0:
         return x
     hd = x.shape[-1]
     freqs = jnp.exp(-math.log(theta) * jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    scale = 1.0
+    if yarn is not None:
+        low, high = yarn_correction_range(yarn, hd, theta)
+        ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+        scale = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                                 # broadcast over heads
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., 0::2], x[..., 1::2]
     r1 = x1 * cos - x2 * sin
     r2 = x2 * cos + x1 * sin

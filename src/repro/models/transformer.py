@@ -2,7 +2,9 @@
 
 arch_type:
   dense  — (norm, GQA attn, norm, MLP) x L
-  moe    — (norm, GQA attn, norm, MoE) x L
+  moe    — (norm, GQA attn, norm, MoE) x L; with ``first_k_dense`` the
+           first layers hold a dense MLP instead ("dense_blocks"), and
+           with ``mla`` every layer's attention is latent (``models.mla``)
   ssm    — (norm, SSD) x L                              (attention-free)
   hybrid — Griffin super-blocks (rec, rec, local-attn) cyclic
   vlm    — decoder with a cross-attn layer every `cross_attn_every` layers
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
+from repro.models import mla as A
 from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import ssm as S
@@ -37,10 +40,14 @@ from repro.sharding.rules import constrain_batch
 # Per-block init
 # ---------------------------------------------------------------------------
 
+def _init_attn(key, cfg: ArchConfig):
+    return A.init_mla(key, cfg) if cfg.mla else L.init_attention(key, cfg)
+
+
 def _init_dense_block(key, cfg: ArchConfig):
     k1, k2 = jax.random.split(key)
     return {"ln1": L.init_rms_norm(cfg.d_model, cfg.dtype("param")),
-            "attn": L.init_attention(k1, cfg),
+            "attn": _init_attn(k1, cfg),
             "ln2": L.init_rms_norm(cfg.d_model, cfg.dtype("param")),
             "mlp": L.init_mlp(k2, cfg)}
 
@@ -48,7 +55,7 @@ def _init_dense_block(key, cfg: ArchConfig):
 def _init_moe_block(key, cfg: ArchConfig):
     k1, k2 = jax.random.split(key)
     return {"ln1": L.init_rms_norm(cfg.d_model, cfg.dtype("param")),
-            "attn": L.init_attention(k1, cfg),
+            "attn": _init_attn(k1, cfg),
             "ln2": L.init_rms_norm(cfg.d_model, cfg.dtype("param")),
             "moe": M.init_moe(k2, cfg)}
 
@@ -114,7 +121,11 @@ def init_params(key, cfg: ArchConfig):
     if t in ("dense",):
         params["blocks"] = _stack(_init_dense_block, kblocks, cfg.num_layers, cfg)
     elif t == "moe":
-        params["blocks"] = _stack(_init_moe_block, kblocks, cfg.num_layers, cfg)
+        nd = cfg.first_k_dense
+        params["blocks"] = _stack(_init_moe_block, kblocks,
+                                  cfg.num_layers - nd, cfg)
+        if nd:
+            params["dense_blocks"] = _stack(_init_dense_block, kextra, nd, cfg)
     elif t == "ssm":
         params["blocks"] = _stack(_init_ssm_block, kblocks, cfg.num_layers, cfg)
     elif t == "hybrid":
@@ -155,20 +166,37 @@ def _maybe_remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn) if cfg.remat else fn
 
 
+def _attention(p, x, cfg, *, window, attn_impl):
+    """Self-attention over the sequence -> (out, cache rows): (k, v), or
+    under MLA the latent rows."""
+    if cfg.mla:
+        return A.mla_forward(p, x, cfg)
+    return L.attention_forward(p, x, cfg, window=window, attn_impl=attn_impl)
+
+
 def _dense_block(bp, x, cfg, *, window=None, attn_impl="xla", collect=False):
-    h, kv = L.attention_forward(bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps),
-                                cfg, window=window, attn_impl=attn_impl)
+    h, kv = _attention(bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps),
+                       cfg, window=window, attn_impl=attn_impl)
     x = x + h
     x = x + L.mlp_forward(bp["mlp"], L.rms_norm(x, bp["ln2"], cfg.norm_eps), cfg)
     return constrain_batch(x), (kv if collect else None)
 
 
-def _moe_block(bp, x, cfg, *, window=None, attn_impl="xla", collect=False):
-    h, kv = L.attention_forward(bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps),
-                                cfg, window=window, attn_impl=attn_impl)
+def _moe_block(bp, x, cfg, *, window=None, attn_impl="xla", collect=False,
+               mask=None):
+    """A routed layer. Collecting a cache (serving's prefill) runs the
+    dropless expert layer over ``mask``'s tokens and gives its pair count
+    as the third output; training runs the capacity dispatch and gives
+    its load-balance loss."""
+    h, kv = _attention(bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps),
+                       cfg, window=window, attn_impl=attn_impl)
     x = x + h
-    y, aux = M.moe_forward(bp["moe"], L.rms_norm(x, bp["ln2"], cfg.norm_eps), cfg)
-    return constrain_batch(x + y), aux, (kv if collect else None)
+    h2 = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+    if collect:
+        y, (_, pairs) = M.moe_dropless(bp["moe"], h2, cfg, mask)
+        return constrain_batch(x + y), pairs, kv
+    y, aux = M.moe_forward(bp["moe"], h2, cfg)
+    return constrain_batch(x + y), aux, None
 
 
 def _ssm_block(bp, x, cfg, collect=False):
@@ -197,8 +225,12 @@ def _cross_block(bp, x, src, cfg, collect=False):
 
 def forward(params, batch, cfg: ArchConfig, *, return_cache: bool = False,
             attn_impl: str = "xla", window: Optional[int] = None):
-    """batch: {"tokens": (B,S) int32} + "enc_emb" (encdec) / "img_emb" (vlm).
-    Returns (logits fp32 (B,S,V), aux_loss scalar, cache-or-None)."""
+    """batch: {"tokens": (B,S) int32} + "enc_emb" (encdec) / "img_emb" (vlm);
+    for moe, optionally "mask" (B,S) bool: the tokens whose routed experts
+    a prefill computes. Returns (logits fp32 (B,S,V), aux_loss scalar,
+    cache-or-None). The cache is {"blocks": {"k","v"}} per layer, or
+    {"blocks": {"latent"}} under MLA; a moe prefill adds "moe_pairs", the
+    (token, held expert) pairs it computed."""
     if window is None:
         window = cfg.sliding_window
     x = constrain_batch(L.embed(params["embed"], batch["tokens"], cfg))
@@ -238,19 +270,37 @@ def forward(params, batch, cfg: ArchConfig, *, return_cache: bool = False,
             cache["blocks"] = dec_cache
 
     elif t in ("dense", "moe"):
+        def rows(kv):
+            if not return_cache:
+                return None
+            return {"latent": kv} if cfg.mla else {"k": kv[0], "v": kv[1]}
+
+        def body(h, bp):
+            h, kv = _dense_block(bp, h, cfg, window=window,
+                                 attn_impl=attn_impl, collect=return_cache)
+            return h, rows(kv)
         if t == "dense":
-            def body(h, bp):
-                h, kv = _dense_block(bp, h, cfg, window=window,
-                                     attn_impl=attn_impl, collect=return_cache)
-                return h, ({"k": kv[0], "v": kv[1]} if return_cache else None)
             x, kvs = jax.lax.scan(_maybe_remat(body, cfg), x, params["blocks"])
         else:
-            def body(h, bp):
+            if cfg.first_k_dense:
+                x, kv0 = jax.lax.scan(_maybe_remat(body, cfg), x,
+                                      params["dense_blocks"])
+
+            def moe_body(h, bp):
                 h, a, kv = _moe_block(bp, h, cfg, window=window,
-                                      attn_impl=attn_impl, collect=return_cache)
-                return h, (a, {"k": kv[0], "v": kv[1]} if return_cache else None)
-            x, (auxs, kvs) = jax.lax.scan(_maybe_remat(body, cfg), x, params["blocks"])
-            aux = auxs.sum()
+                                      attn_impl=attn_impl,
+                                      collect=return_cache,
+                                      mask=batch.get("mask"))
+                return h, (a, rows(kv))
+            x, (auxs, kvs) = jax.lax.scan(_maybe_remat(moe_body, cfg), x,
+                                          params["blocks"])
+            if return_cache:
+                cache["moe_pairs"] = auxs
+                if cfg.first_k_dense:
+                    kvs = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                       kv0, kvs)
+            else:
+                aux = auxs.sum()
         if return_cache:
             cache["blocks"] = kvs
 
@@ -336,6 +386,9 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
         window = cfg.sliding_window
     t = cfg.arch_type
     cd = cfg.dtype("compute")
+    if cfg.mla or cfg.first_k_dense:
+        raise ValueError("latent attention and leading dense layers decode "
+                         "from the paged pool only (serving.decode)")
     if t in ("dense", "moe"):
         one = L.init_attn_cache(batch, cfg, seq_len, window)
         return {"blocks": jax.tree.map(
@@ -403,7 +456,7 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig,
             if t == "dense":
                 h = h + L.mlp_forward(bp["mlp"], h2, cfg)
             else:
-                y, _ = M.moe_forward(bp["moe"], h2, cfg)
+                y, _ = M.moe_dropless(bp["moe"], h2, cfg)
                 h = h + y
             return h, nc
         x, nc = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))

@@ -45,6 +45,14 @@ Attention implementations (``attn_impl``):
   position order, so this arm falls back to the XLA masked path — the
   server surfaces that fallback (warning + obs note) instead of hiding
   it.
+
+Latent attention (``ArchConfig.mla``) decodes in the absorbed form
+(``models.mla.mla_decode``) from one ``(L, P, page, latent_dim)`` pool
+of ``[c | k_pe]`` rows on the same tables, carried through the layer
+scans (the leading dense layers', then the routed layers') the same way;
+it reads only those rows, through the ``"xla"`` gather, and each step
+writes one row a slot and layer. Every routed layer runs the dropless
+expert layer (``models.moe.moe_dropless``) over the active slots.
 """
 from __future__ import annotations
 
@@ -56,6 +64,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.kernels.paged_attention.ref import valid_mask as _valid_mask
 from repro.models import layers as L
+from repro.models import mla as A
 from repro.models import moe as M
 
 ATTN_IMPLS = ("xla", "pallas", "pallas_gather")
@@ -130,17 +139,55 @@ def paged_attention_decode(p, x, k_pool, v_pool, layer, table, pos, active,
     return y, (k_pool, v_pool)
 
 
+def paged_mla_decode(p, x, pool, layer, table, pos, active, cfg: ArchConfig,
+                     *, gather_pages: Optional[int] = None):
+    """One latent-attention layer's decode over the latent pool.
+
+    pool: (L, P, page, latent_dim) every layer's rows; the rest as
+    ``paged_attention_decode``. Writes each active slot's new row, then
+    attends over the first ``gather_pages`` pages of its table.
+    Returns (out (B,1,D), pool)."""
+    B = x.shape[0]
+    page, C = pool.shape[2], pool.shape[3]
+    max_pages = table.shape[1]
+    row = A.latent_rows(p, x, pos[:, None], cfg)[:, 0].astype(pool.dtype)
+    pid = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0]
+    in_page = pos % page
+    old = pool[layer, pid, in_page]
+    pool = pool.at[layer, pid, in_page].set(
+        jnp.where(active[:, None], row, old))
+    gp = max_pages if gather_pages is None else min(gather_pages, max_pages)
+    rows = pool[layer, table[:, :gp]].reshape(B, gp * page, C)
+    valid = _valid_mask(pos, max_pages * page, None)[:, :gp * page]
+    return A.mla_decode(p, x, rows, valid, pos, cfg), pool
+
+
+def _held_experts(blocks):
+    """Routed layers' blocks without their expert weights, and those
+    weights as every layer's stack: the layer scan slices the first, and
+    ``moe_dropless(..., layer=)`` reads the second in place."""
+    names = ("w_gate", "w_up", "w_down")
+    moe = blocks["moe"]
+    return (dict(blocks, moe={n: v for n, v in moe.items() if n not in names}),
+            {n: moe[n] for n in names})
+
+
 def paged_decode_step(params, pages, table, tokens, pos, active,
                       cfg: ArchConfig, *, window: Optional[int] = None,
                       attn_impl: str = "xla",
-                      gather_pages: Optional[int] = None):
+                      gather_pages: Optional[int] = None,
+                      stats: Optional[dict] = None):
     """One continuous-batching decode step for dense/moe stacks.
 
-    pages: {"k","v"}: (L, P, page, K, hd); table: (B, max_pages) shared by
-    all layers; tokens: (B,1) int32; pos: (B,) int32; active: (B,) bool.
-    Returns (logits (B,1,V) fp32, new pages). Mirrors
-    ``transformer.decode_step``'s layer scan so the math bit-matches; the
-    pools are carried through it and written in place (module docstring).
+    pages: {"k","v"}: (L, P, page, K, hd), or {"latent"} under MLA;
+    table: (B, max_pages) shared by all layers; tokens: (B,1) int32; pos:
+    (B,) int32; active: (B,) bool. Returns (logits (B,1,V) fp32, new
+    pages). Mirrors ``transformer.decode_step``'s layer scan so the math
+    bit-matches; the pools are carried through it and written in place
+    (module docstring). A routed stack puts its step's counts into
+    ``stats`` when given: ``experts_hit`` (held experts given a pair,
+    summed over layers) and ``pairs`` ((token, held expert) pairs), values
+    of the same traced computation.
     """
     if window is None:
         window = cfg.sliding_window
@@ -150,28 +197,46 @@ def paged_decode_step(params, pages, table, tokens, pos, active,
     t = cfg.arch_type
     if t not in ("dense", "moe"):
         raise ValueError(f"paged decode supports dense/moe, not {t!r}")
-    x = L.embed(params["embed"], tokens, cfg)
+    if cfg.mla and (attn_impl != "xla" or window is not None):
+        raise ValueError("latent attention decodes through the xla "
+                         "gather over a full (non-ring) cache")
+    nd = cfg.first_k_dense
+    blocks, experts = ((params["blocks"], None) if t == "dense"
+                       else _held_experts(params["blocks"]))
 
-    def body(carry, xs):
-        h, kp, vp = carry
-        bp, layer = xs
+    def attend(h, pages, bp, layer):
+        hn = L.rms_norm(h, bp["ln1"], cfg.norm_eps)
+        if cfg.mla:
+            a, pool = paged_mla_decode(bp["attn"], hn, pages["latent"], layer,
+                                       table, pos, active, cfg,
+                                       gather_pages=gather_pages)
+            return a, {"latent": pool}
         a, (kp, vp) = paged_attention_decode(
-            bp["attn"], L.rms_norm(h, bp["ln1"], cfg.norm_eps), kp, vp,
-            layer, table, pos, active, cfg, window=window,
-            attn_impl=attn_impl, gather_pages=gather_pages)
+            bp["attn"], hn, pages["k"], pages["v"], layer, table, pos, active,
+            cfg, window=window, attn_impl=attn_impl, gather_pages=gather_pages)
+        return a, {"k": kp, "v": vp}
+
+    def body(carry, xs):                  # a leading dense or a stacked layer
+        h, pages = carry
+        bp, layer = xs
+        a, pages = attend(h, pages, bp, layer)
         h = h + a
         h2 = L.rms_norm(h, bp["ln2"], cfg.norm_eps)
-        if t == "dense":
-            h = h + L.mlp_forward(bp["mlp"], h2, cfg)
-        else:
-            y, _ = M.moe_forward(bp["moe"], h2, cfg)
-            h = h + y
-        return (h, kp, vp), None
+        if "mlp" in bp:
+            return (h + L.mlp_forward(bp["mlp"], h2, cfg), pages), None
+        y, counts = M.moe_dropless(dict(bp["moe"], **experts), h2, cfg,
+                                   active[:, None], layer=layer - nd)
+        return (h + y, pages), counts
 
-    n_layers = pages["k"].shape[0]
-    (x, nk, nv), _ = jax.lax.scan(
-        body, (x, pages["k"], pages["v"]),
-        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+    x = L.embed(params["embed"], tokens, cfg)
+    n_layers = next(iter(pages.values())).shape[0]
+    if nd:
+        (x, pages), _ = jax.lax.scan(
+            body, (x, pages),
+            (params["dense_blocks"], jnp.arange(nd, dtype=jnp.int32)))
+    (x, pages), counts = jax.lax.scan(
+        body, (x, pages), (blocks, jnp.arange(nd, n_layers, dtype=jnp.int32)))
+    if stats is not None and counts is not None:
+        stats.update(experts_hit=counts[0].sum(), pairs=counts[1].sum())
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
-    return logits, {"k": nk, "v": nv}
+    return L.unembed(params["embed"], x, cfg), pages

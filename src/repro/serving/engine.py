@@ -31,7 +31,8 @@ Prefill modes:
   scattered into the slot's pages. One call instead of P steps — the
   prefill hot path — numerically allclose to scan, not bitwise
   (parallel vs stepwise attention reduction order). Full-window caches
-  only: a ring-wrapped scatter would need last-writer selection.
+  only: a ring-wrapped scatter would need last-writer selection. Under
+  latent attention it writes each token's latent row instead of K/V.
 
 Decode cost tracks live context, not pool capacity:
 - ``attn_impl="pallas"`` routes decode (and the scan-prefill inner
@@ -218,13 +219,22 @@ class ContinuousServer:
 
         S = self.spec.num_slots
         win, impl = self.window, self.attn_impl
+        # a routed stack's programs also return their expert counts, which
+        # the host fetches with the tokens at the step's one sync
+        self._moe = cfg.moe is not None
 
         def _step(params, pages, table, tokens, pos, active, *,
                   gather_pages: Optional[int] = None):
+            stats = {}
             logits, pages = paged_decode_step(
                 params, pages, table, tokens, pos, active, cfg,
-                window=win, attn_impl=impl, gather_pages=gather_pages)
-            return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), pages
+                window=win, attn_impl=impl, gather_pages=gather_pages,
+                stats=stats)
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            if stats:
+                tok = (tok, jnp.stack([stats["experts_hit"],
+                                       stats["pairs"]]))
+            return tok, pages
 
         self._step_impl = _step
         self._step_cache: Dict[Optional[int], Callable] = {}
@@ -237,38 +247,47 @@ class ContinuousServer:
             def body(pg, t):
                 tok = jax.lax.dynamic_slice_in_dim(prompts, t, 1, axis=1)
                 act = admit & (t < plens)
+                stats = {}
                 logits, pg = paged_decode_step(
                     params, pg, table, tok, jnp.full((S,), t, jnp.int32),
                     act, cfg, window=win, attn_impl=impl,
-                    gather_pages=gather_pages)
-                return pg, jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    gather_pages=gather_pages, stats=stats)
+                tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                return pg, (tok, stats.get("pairs"))
 
-            pages, toks = jax.lax.scan(body, pages,
-                                       jnp.arange(Pb, dtype=jnp.int32))
-            return pages, toks                       # toks: (Pb, S)
+            pages, (toks, pairs) = jax.lax.scan(
+                body, pages, jnp.arange(Pb, dtype=jnp.int32))
+            if self._moe:                            # toks: (Pb, S)
+                return pages, (toks, pairs.sum())
+            return pages, toks
 
         def _parallel_prefill(params, pages, table, prompts, plens, admit, *,
                               gather_pages: Optional[int] = None):
             B, Pb = prompts.shape
             page = self.spec.page_size
-            logits, _, cache = T.forward(params, {"tokens": prompts}, cfg,
-                                         return_cache=True, attn_impl=impl,
-                                         window=win)
             tpos = jnp.arange(Pb)[None, :]                     # (1, Pb)
             act = admit[:, None] & (tpos < plens[:, None])     # (B, Pb)
+            batch = {"tokens": prompts}
+            if self._moe:
+                batch["mask"] = act        # route the prompt tokens only
+            logits, _, cache = T.forward(params, batch, cfg,
+                                         return_cache=True, attn_impl=impl,
+                                         window=win)
             pidx = jnp.broadcast_to(tpos // page, (B, Pb))
             pid = jnp.take_along_axis(table, pidx, axis=1)     # (B, Pb)
             inpg = jnp.broadcast_to(tpos % page, (B, Pb))
-            actx = act[None, :, :, None, None]
             new_pages = {}
-            for name in ("k", "v"):
-                pool = pages[name]                             # (L,P,pg,K,hd)
+            for name, pool in pages.items():     # (L,P,pg,K,hd) / (L,P,pg,C)
                 rows = cache["blocks"][name].astype(pool.dtype)
-                old = pool[:, pid, inpg]                       # (L,B,Pb,K,hd)
+                actx = act[(None, slice(None), slice(None))
+                           + (None,) * (pool.ndim - 3)]
+                old = pool[:, pid, inpg]                       # (L,B,Pb,...)
                 new_pages[name] = pool.at[:, pid, inpg].set(
                     jnp.where(actx, rows, old))
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, Pb)
-            return new_pages, toks.T                           # (Pb, B)
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32).T  # (Pb, B)
+            if self._moe:
+                toks = (toks, cache["moe_pairs"].sum())
+            return new_pages, toks
 
         self._prefill_impl = (_scan_prefill if prefill_mode == "scan"
                               else _parallel_prefill)
@@ -385,6 +404,15 @@ class ContinuousServer:
         lane_ctr = reg.counter("serving.prefill_lane_tokens")
         done_ctr = reg.counter("serving.requests_completed")
         tok_ctr = reg.counter("serving.tokens_generated")
+        if self._moe:
+            # expert work per decode step and per prefill call (with each
+            # admitted prompt's length under its call's index), so a
+            # reader can take just the calls a profile holds
+            hit_ser = reg.series("serving.moe_decode_experts_hit")
+            dpair_ser = reg.series("serving.moe_decode_pairs")
+            live_ser = reg.series("serving.decode_live_tokens")
+            ppair_ser = reg.series("serving.moe_prefill_pairs")
+            plen_ser = reg.series("serving.prefill_call_prompt_tokens")
 
         reqs = sorted(requests, key=lambda r: r.arrival)
         if self.window is None:
@@ -483,9 +511,17 @@ class ContinuousServer:
                         self.params, self.pages, jnp.asarray(alloc.tables),
                         jnp.asarray(prompts), jnp.asarray(plens),
                         jnp.asarray(admit))
-                    toks = np.asarray(toks)        # (Pb, S); sync
+                    if self._moe:
+                        toks, pairs = jax.device_get(toks)   # one sync
+                    else:
+                        toks = np.asarray(toks)    # (Pb, S); sync
                 tnow = now()
                 with spans.span("serve.emit"):
+                    if self._moe:
+                        call = len(ppair_ser)
+                        ppair_ser.append(int(pairs), step=call)
+                        for s in admits:
+                            plen_ser.append(len(slot_req[s].prompt), step=call)
                     for s in admits:
                         r = slot_req[s]
                         prefill_s.append(tnow - tpf, step=r.rid)
@@ -523,10 +559,19 @@ class ContinuousServer:
                     tok, self.pages = self._step_fn(gp)(
                         self.params, self.pages, *inputs)
                 with spans.span("serve.decode.sync"):
-                    tok = np.asarray(tok)
+                    if self._moe:
+                        tok, counts = jax.device_get(tok)    # one sync
+                    else:
+                        tok = np.asarray(tok)
             tnow = now()
             with spans.span("serve.emit"):
                 step_s.append(tnow - tstep, step=steps)
+                if self._moe:
+                    hit_ser.append(int(counts[0]), step=steps)
+                    dpair_ser.append(int(counts[1]), step=steps)
+                    # a slot at position p reads rows 0..p
+                    live_ser.append(int(slot_pos[active].sum()
+                                        + active.sum()), step=steps)
                 steps += 1
                 for s in np.nonzero(active)[0]:
                     r = slot_req[s]

@@ -3,8 +3,10 @@ dense ``(B, W, K, hd)`` ring-buffer layout from ``models.layers``.
 
 Storage contract
 ----------------
-The device pool is ``(L, num_pages, page_size, K, hd)`` for k and v; a
-slot's logical cache is ``pages_per_slot`` pages whose ids live in its
+The device pool is ``(L, num_pages, page_size, K, hd)`` for k and v —
+or, for latent attention (``ArchConfig.mla``), one ``(L, num_pages,
+page_size, latent_dim)`` pool of ``[c | k_pe]`` rows, on the same pages
+and tables; a slot's logical cache is ``pages_per_slot`` pages whose ids live in its
 page-table row, and gathering ``pool[table[b]]`` then reshaping yields
 exactly the dense ``(W, K, hd)`` ring buffer (``W = pages_per_slot *
 page_size``) the reference ``attention_decode`` reads — which is what
@@ -44,6 +46,7 @@ class PagedCacheSpec:
     head_dim: int
     dtype: str = "float32"
     extra_pages: int = 0  # slack beyond slots*pages_per_slot (besides scratch)
+    latent_dim: Optional[int] = None  # one latent row per token, not K/V
 
     @property
     def seq_capacity(self) -> int:
@@ -69,14 +72,18 @@ class PagedCacheSpec:
                    pages_per_slot=W // page_size, num_layers=cfg.num_layers,
                    kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
                    dtype=cfg.dtype("compute").name,
-                   extra_pages=extra_pages)
+                   extra_pages=extra_pages,
+                   latent_dim=cfg.mla.latent_dim if cfg.mla else None)
 
 
 def init_pages(spec: PagedCacheSpec):
-    """Zero-filled device pools: {"k","v"}: (L, P, page, K, hd)."""
-    shape = (spec.num_layers, spec.num_pages, spec.page_size,
-             spec.kv_heads, spec.head_dim)
+    """Zero-filled device pools: {"k","v"}: (L, P, page, K, hd), or
+    {"latent"}: (L, P, page, latent_dim)."""
+    lead = (spec.num_layers, spec.num_pages, spec.page_size)
     dt = jnp.dtype(spec.dtype)
+    if spec.latent_dim is not None:
+        return {"latent": jnp.zeros(lead + (spec.latent_dim,), dtype=dt)}
+    shape = lead + (spec.kv_heads, spec.head_dim)
     return {"k": jnp.zeros(shape, dtype=dt), "v": jnp.zeros(shape, dtype=dt)}
 
 

@@ -164,3 +164,56 @@ def test_phi4_xla_decode_step_writes_pool_in_place(one_chip):
     assert not moves, moves
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < layer_bytes, mem.temp_size_in_bytes
+
+
+def _dsv2_lite_share():
+    """DeepSeek-V2-Lite at its published widths, 16 of 64 experts held."""
+    from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, YarnConfig
+    return ArchConfig(
+        name="dsv2-lite-ep4", arch_type="moe", num_layers=27, d_model=2048,
+        num_heads=16, num_kv_heads=16, d_ff=10944, vocab_size=102400,
+        norm_eps=1e-6, first_k_dense=1, param_dtype="bfloat16",
+        moe=MoEConfig(num_experts=16, top_k=6, d_ff_expert=1408,
+                      num_shared_experts=2, router_experts=64,
+                      norm_topk_prob=False),
+        mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                      qk_rope_head_dim=64, v_head_dim=128),
+        yarn=YarnConfig(factor=40, original_max_position_embeddings=4096,
+                        mscale=0.707, mscale_all_dim=0.707))
+
+
+@pytest.mark.parametrize("tokens", [4, 512])
+def test_dsv2_lite_expert_layer_compiles_to_one_grouped_matmul_kernel(
+        one_chip, tokens):
+    """The held experts' pairs run through XLA's grouped-matmul kernel
+    (``ragged_dot``), one call a projection, at a decode step's 4 tokens
+    and a 512-token prefill's."""
+    from repro.models import moe
+    cfg = _dsv2_lite_share()
+    p = jax.eval_shape(lambda k: moe.init_moe(k, cfg), jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), p)
+    x = _spec(one_chip, (tokens, cfg.d_model), jnp.bfloat16)
+    text = jax.jit(lambda p, x: moe.moe_dropless(p, x, cfg)).lower(
+        p, x).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%ragged-dot-none\S* = \S+ custom-call\(",
+                       text, flags=re.M)
+    assert len(calls) == 3, "three grouped-matmul kernels"
+
+
+def test_dsv2_lite_latent_decode_layer_compiles(one_chip):
+    """One latent-attention layer's absorbed decode over the 576-wide
+    pool at 4 slots and a 3,072-token table compiles for the chip."""
+    from repro.models import mla
+    from repro.serving.decode import paged_mla_decode
+    cfg = _dsv2_lite_share()
+    p = jax.eval_shape(lambda k: mla.init_mla(k, cfg), jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: _spec(one_chip, a.shape, jnp.bfloat16), p)
+    S = 4
+    args = (p, _spec(one_chip, (S, 1, 2048), jnp.bfloat16),
+            _spec(one_chip, (27, 1 + S * 192, 16, 576), jnp.bfloat16),
+            _spec(one_chip, (), jnp.int32),
+            _spec(one_chip, (S, 192), jnp.int32),
+            _spec(one_chip, (S,), jnp.int32), _spec(one_chip, (S,), bool))
+    compiled = jax.jit(lambda *a: paged_mla_decode(*a, cfg)).lower(
+        *args).compile()
+    assert compiled.memory_analysis() is not None
