@@ -21,6 +21,11 @@ instead of sleeping, so a 50-request trace benches in compute time while
 queueing delays stay real. Per-request output is independent of batch
 composition (pinned in tests), so admission timing never changes tokens.
 
+A prefill call computes only the prompts admitted together: its batch is
+their count rounded up a power-of-two lane ladder (1, 2, 4, ... capped
+at ``slots``), each lane on its slot's table row, pad lanes inactive on
+scratch page 0, and ``warmup`` compiles every rung.
+
 Prefill modes:
 - ``"scan"`` (default): a jitted scan of the paged decode step over
   prompt positions, bucketed by prompt length — bitwise-identical cache
@@ -217,7 +222,6 @@ class ContinuousServer:
             warnings.warn(self._fallback_note, stacklevel=2)
             self.registry.note(self._fallback_note)
 
-        S = self.spec.num_slots
         win, impl = self.window, self.attn_impl
         # a routed stack's programs also return their expert counts, which
         # the host fetches with the tokens at the step's one sync
@@ -242,14 +246,14 @@ class ContinuousServer:
 
         def _scan_prefill(params, pages, table, prompts, plens, admit, *,
                           gather_pages: Optional[int] = None):
-            Pb = prompts.shape[1]
+            B, Pb = prompts.shape
 
             def body(pg, t):
                 tok = jax.lax.dynamic_slice_in_dim(prompts, t, 1, axis=1)
                 act = admit & (t < plens)
                 stats = {}
                 logits, pg = paged_decode_step(
-                    params, pg, table, tok, jnp.full((S,), t, jnp.int32),
+                    params, pg, table, tok, jnp.full((B,), t, jnp.int32),
                     act, cfg, window=win, attn_impl=impl,
                     gather_pages=gather_pages, stats=stats)
                 tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
@@ -257,7 +261,7 @@ class ContinuousServer:
 
             pages, (toks, pairs) = jax.lax.scan(
                 body, pages, jnp.arange(Pb, dtype=jnp.int32))
-            if self._moe:                            # toks: (Pb, S)
+            if self._moe:                            # toks: (Pb, B)
                 return pages, (toks, pairs.sum())
             return pages, toks
 
@@ -366,8 +370,9 @@ class ContinuousServer:
         return ladder
 
     def warmup(self, prompt_lens: Sequence[int] = ()) -> None:
-        """Compile the decode-step gather ladder and the prefill buckets
-        for the given prompt lengths without touching any state: an
+        """Compile the decode-step gather ladder, and the prefill buckets
+        for the given prompt lengths at every lane width of the ladder
+        (1, 2, 4, ... up to ``slots``), without touching any state: an
         all-inactive call writes back exactly what it reads."""
         S = self.spec.num_slots
         table = jnp.asarray(self.alloc.tables)
@@ -379,11 +384,16 @@ class ContinuousServer:
                 jnp.zeros((S, 1), jnp.int32), off, inact)
             jax.block_until_ready(tok)
         cap = self.spec.seq_capacity if self.window is None else None
+        widths = sorted({_bucket(n, S) for n in range(1, S + 1)})
         for p in sorted({_bucket(int(p), cap) for p in prompt_lens}):
             fn = self._prefill_fn(p)
-            self.pages, toks = fn(self.params, self.pages, table,
-                                  jnp.zeros((S, p), jnp.int32), off, inact)
-            jax.block_until_ready(toks)
+            for A in widths:
+                self.pages, toks = fn(
+                    self.params, self.pages,
+                    jnp.zeros((A, self.spec.pages_per_slot), jnp.int32),
+                    jnp.zeros((A, p), jnp.int32), jnp.zeros((A,), jnp.int32),
+                    jnp.zeros((A,), bool))
+                jax.block_until_ready(toks)
 
     def run(self, requests: Sequence[Request]) -> ServeReport:
         """Serve every request; returns per-request accounting. Each
@@ -487,34 +497,40 @@ class ContinuousServer:
                     qi += 1
                     n_active += 1
                 if admits:
-                    plens = np.array([len(slot_req[s].prompt) if slot_req[s]
-                                      else 0 for s in range(S)], np.int32)
+                    # lane i prefills admits[i]; pad lanes stay inactive on
+                    # scratch page 0, never on a real slot's row: two
+                    # writers of one page in a scatter land in no set order
+                    A = _bucket(len(admits), S)
                     pmax = max(len(slot_req[s].prompt) for s in admits)
                     Pb = _bucket(pmax, cap if self.window is None else None)
-                    prompts = np.zeros((S, Pb), np.int32)
-                    admit = np.zeros(S, bool)
-                    for s in admits:
+                    prompts = np.zeros((A, Pb), np.int32)
+                    plens = np.zeros(A, np.int32)
+                    admit = np.zeros(A, bool)
+                    table = np.zeros((A, spec.pages_per_slot), np.int32)
+                    for i, s in enumerate(admits):
                         r = slot_req[s]
-                        prompts[s, :len(r.prompt)] = r.prompt[:Pb]
-                        admit[s] = True
-                    prompt_ctr.inc(int(plens[admit].sum()))
-                    lane_ctr.inc(S * Pb)
+                        prompts[i, :len(r.prompt)] = r.prompt[:Pb]
+                        plens[i] = len(r.prompt)
+                        admit[i] = True
+                        table[i] = alloc.tables[s]
+                    prompt_ctr.inc(int(plens.sum()))
+                    lane_ctr.inc(A * Pb)
                     admit_rids = [slot_req[s].rid for s in admits]
 
             # -- prefill the admitted slots (one bucketed jitted call) ----
             if admits:
                 tpf = now()
                 with spans.span("serve.prefill", lanes=len(admits),
-                                bucket=Pb, rids=admit_rids):
+                                width=A, bucket=Pb, rids=admit_rids):
                     fn = self._prefill_fn(Pb)
                     self.pages, toks = fn(
-                        self.params, self.pages, jnp.asarray(alloc.tables),
+                        self.params, self.pages, jnp.asarray(table),
                         jnp.asarray(prompts), jnp.asarray(plens),
                         jnp.asarray(admit))
                     if self._moe:
                         toks, pairs = jax.device_get(toks)   # one sync
                     else:
-                        toks = np.asarray(toks)    # (Pb, S); sync
+                        toks = np.asarray(toks)    # (Pb, A); sync
                 tnow = now()
                 with spans.span("serve.emit"):
                     if self._moe:
@@ -522,11 +538,11 @@ class ContinuousServer:
                         ppair_ser.append(int(pairs), step=call)
                         for s in admits:
                             plen_ser.append(len(slot_req[s].prompt), step=call)
-                    for s in admits:
+                    for i, s in enumerate(admits):
                         r = slot_req[s]
                         prefill_s.append(tnow - tpf, step=r.rid)
                         slot_pf_end[s] = tnow
-                        first = int(toks[len(r.prompt) - 1, s])
+                        first = int(toks[len(r.prompt) - 1, i])
                         out_tokens[r.rid].append(first)
                         out_times[r.rid].append(tnow)
                         tok_ctr.inc()

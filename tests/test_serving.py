@@ -12,6 +12,9 @@ The load-bearing contracts, in order:
   low bits, so cross-width comparisons pin tokens — see decode.py);
 - the flash-attention decode hot path and the parallel prefill are
   numerically allclose to the XLA/scan references;
+- a prefill call is as wide as its admissions' power-of-two lane bucket,
+  serves the tokens the full slot-width call serves, its pad lanes write
+  nothing, and ``warmup`` compiles every width;
 - the load generator is reproducible and rid-stable across rates;
 - the page allocator recycles and the serving planner's discrete-event
   model behaves monotonically.
@@ -510,11 +513,11 @@ def test_server_loop_spans_cover_the_run(prefill_mode):
     pf = [r for r in recs if r.name == "serve.prefill"]
     assert sorted(rid for r in pf for rid in r.attrs["rids"]) == \
         sorted(r.rid for r in reqs)
-    # the counters: real prompt tokens, and slots x bucket per call
+    # the counters: real prompt tokens, and width x bucket per call
     assert reg.counter("serving.prefill_tokens").value == \
         sum(len(r.prompt) for r in reqs)
     assert reg.counter("serving.prefill_lane_tokens").value == \
-        sum(srv.spec.num_slots * r.attrs["bucket"] for r in pf)
+        sum(r.attrs["width"] * r.attrs["bucket"] for r in pf)
     # the per-step gauges that nothing read are gone
     assert not {"serving.batch_occupancy", "serving.pages_in_use"} & \
         set(reg.names())
@@ -565,6 +568,137 @@ def test_server_programs_compile_under_their_names():
                                        inact)
     assert "module @jit_serve_decode_step " in step.as_text()
     assert "module @jit_serve_prefill " in prefill.as_text()
+
+
+# ---------------------------------------------------------------------------
+# prefill lanes: a call is as wide as its admissions, up the ladder
+# ---------------------------------------------------------------------------
+
+def _tiny_mla_moe():
+    """The benchmark's CPU stand-in for DeepSeek-V2-Lite (latent attention
+    with YaRN, a leading dense layer, 4 held experts of a 16-wide router,
+    a shared expert): its program config and the family's seeded
+    weights."""
+    import importlib.util
+    import json
+    import pathlib
+    chip = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    spec = importlib.util.spec_from_file_location(
+        "mla_moe_family", chip / "families" / "mla_moe.py")
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    cfg = json.loads((chip / "configs" / "tiny-mla-moe.json").read_text())
+    return (fam.program_config(cfg),
+            jax.jit(fam.weights_fn(cfg))(jax.random.PRNGKey(5)))
+
+
+@pytest.mark.parametrize("arch,prefill_mode,n", [
+    ("dense", "scan", 1), ("dense", "scan", 2), ("dense", "scan", 3),
+    ("dense", "parallel", 1), ("dense", "parallel", 2),
+    ("dense", "parallel", 3),
+    ("mla-moe", "parallel", 1),
+])
+def test_prefill_lanes_are_the_admissions_bucket(arch, prefill_mode, n):
+    """``n`` prompts admitted at once into four slots are prefilled in one
+    call ``_bucket(n, 4)`` lanes wide (1, 2, 4). Per request, its token
+    and (routed stacks) the call's expert pairs equal those of the same
+    prompts prefilled four lanes wide, the other lanes inactive, and its
+    cache rows agree to float32 rounding (a batch width re-tiles XLA's
+    reductions); outside those rows no page changes: pad lanes, on
+    scratch page 0, write nothing to it or to any slot's pages."""
+    from repro.obs import spans
+    import repro.serving.engine as E
+    if arch == "dense":
+        cfg = _cfg()
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
+        page, max_seq, lens = 4, 16, (7, 5, 8)
+    else:
+        cfg, params = _tiny_mla_moe()
+        page, max_seq, lens = 8, 64, (21,)
+    S = 4
+    rng = np.random.default_rng(n)
+    reqs = [E.Request(rid=i, arrival=0.0, gen=1, prompt=rng.integers(
+        cfg.vocab_size, size=lens[i]).astype(np.int32)) for i in range(n)]
+    srv = ContinuousServer(cfg, params, slots=S, page_size=page,
+                           max_seq=max_seq, prefill_mode=prefill_mode)
+    before = {k: rng.standard_normal(v.shape).astype(v.dtype)
+              for k, v in jax.device_get(srv.pages).items()}
+
+    # the four-lane call: slot s is lane s, the slots not admitted inactive
+    Pb = E._bucket(max(lens[:n]), srv.spec.seq_capacity)
+    alloc = PageAllocator(srv.spec)
+    prompts = np.zeros((S, Pb), np.int32)
+    plens = np.zeros(S, np.int32)
+    for r in reqs:
+        alloc.ensure(r.rid, len(r.prompt))
+        prompts[r.rid, :len(r.prompt)] = r.prompt
+        plens[r.rid] = len(r.prompt)
+    wide, wtoks = srv._prefill_fn(Pb)(
+        srv.params, jax.tree.map(jnp.asarray, before),
+        jnp.asarray(alloc.tables), jnp.asarray(prompts), jnp.asarray(plens),
+        jnp.asarray(plens > 0))
+    wide, wtoks = jax.device_get((wide, wtoks))
+
+    srv.pages = jax.tree.map(jnp.asarray, before)
+    tracer = spans.Tracer()
+    with spans.install(tracer):
+        rep = srv.run(reqs)
+    got = jax.device_get(srv.pages)
+    pf = [r for r in tracer.records() if r.name == "serve.prefill"]
+    assert [(r.attrs["lanes"], r.attrs["width"]) for r in pf] == \
+        [(n, E._bucket(n, S))]
+    if arch != "dense":
+        wtoks, wpairs = wtoks
+        assert srv.registry.series("serving.moe_prefill_pairs").values == \
+            [int(wpairs)]
+    for r in reqs:
+        assert rep.tokens[r.rid].tolist() == \
+            [int(wtoks[len(r.prompt) - 1, r.rid])], r.rid
+    written = np.zeros(before[next(iter(before))].shape[:3], bool)
+    for r in reqs:
+        t = np.arange(len(r.prompt))
+        written[:, alloc.tables[r.rid, t // page], t % page] = True
+    for k in before:
+        assert np.array_equal(got[k][~written], before[k][~written]), k
+        np.testing.assert_allclose(
+            got[k][written].astype(np.float32),
+            wide[k][written].astype(np.float32), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("prefill_mode", ["scan", "parallel"])
+def test_warmup_compiles_every_lane_width(prefill_mode):
+    """After ``warmup``, a run whose prefill calls admit 1, 2 and 3
+    prompts at once (widths 1, 2, 4) compiles nothing."""
+    from repro.obs import spans
+    import repro.serving.engine as E
+    cfg = _cfg()
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(9)
+    arrivals = (0.0, 10.0, 10.0, 20.0, 20.0, 20.0)
+    reqs = [E.Request(rid=i, arrival=a, gen=3, prompt=rng.integers(
+        cfg.vocab_size, size=int(rng.integers(4, 12))).astype(np.int32))
+        for i, a in enumerate(arrivals)]
+    srv = ContinuousServer(cfg, params, slots=4, page_size=4, max_seq=32,
+                           prefill_mode=prefill_mode)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        srv.warmup([len(r.prompt) for r in reqs])
+        warm = len(compiles)
+        tracer = spans.Tracer()
+        with spans.install(tracer):
+            rep = srv.run(reqs)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert warm > 0 and len(compiles) == warm
+    assert [r.attrs["width"] for r in tracer.records()
+            if r.name == "serve.prefill"] == [1, 2, 4]
+    assert rep.total_tokens == 3 * len(reqs)
 
 
 # ---------------------------------------------------------------------------
